@@ -135,4 +135,7 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    from repro.core.engine_jax import enable_compile_cache
+
+    enable_compile_cache()
     main()

@@ -326,7 +326,10 @@ def _effective_classes(mode, cls, deadline, remaining, src_m, dst_m, bw_in, bw_o
         return eff
     lim = np.minimum(bw_in[dst_m], bw_out[src_m])
     need = remaining / np.maximum(lim, EPS)
-    urgent = (eff > CLASS_TRAINING) & ((deadline - now) <= need)
+    # + EPS: the complement of the wake rule (a wake is scheduled only
+    # while deadline - need > now + EPS), so a flow woken at its own
+    # escalation time escalates whatever the rounding of deadline - need
+    urgent = (eff > CLASS_TRAINING) & ((deadline - now) <= need + EPS)
     if not urgent.any():
         return eff
     top = min(int(eff.min()), CLASS_TRAINING) - 1

@@ -15,9 +15,8 @@ numpy engine (the reference implementation):
     wakes, remaining-volume decrement, per-instance segment pointers);
   * all five built-in rate policies (oes / oes_strict / fifo / mrtf /
     omcoflow) are expressed as masked ``[B, EG]`` array programs over the
-    per-instance ``[B, M]`` NIC capacity rows — the sequential waterfill
-    (fifo/mrtf) optionally runs as a Pallas kernel
-    (``repro.kernels.waterfill``, Mosaic-fallback idiom) where it pays;
+    per-instance ``[B, M]`` NIC capacity rows (the fifo/mrtf sequential
+    waterfill is a ``fori_loop`` over the priority order);
   * ``ShapedPolicy`` class shaping is a statically unrolled loop over the
     run's concrete class levels (plus the EDF escalation level in
     deadline mode), each level rated against the leftovers of the levels
@@ -48,6 +47,7 @@ policy, shaping levels, trace length, record, utilization).
 from __future__ import annotations
 
 import os
+from pathlib import Path
 from typing import (
     TYPE_CHECKING,
     Any,
@@ -106,16 +106,20 @@ JAX_POLICIES = ("oes", "oes_strict", "fifo", "mrtf", "omcoflow")
 
 _RUNNERS: Dict[tuple, object] = {}
 
+# fixed, location-derived default for JAX's persistent compilation cache
+# (the directory is part of the cache key, so it must not move per run)
+REPO_COMPILE_CACHE = Path(__file__).resolve().parents[3] / ".jax_cache"
 
-def _use_pallas_waterfill() -> bool:
-    """Pallas waterfill where it pays: opt-in via env on CPU (interpret
-    mode traces the same program XLA already runs), default on TPU."""
-    env = os.environ.get("REPRO_WATERFILL_PALLAS", "").strip().lower()
-    if env in ("1", "true", "yes"):
-        return True
-    if env in ("0", "false", "no"):
-        return False
-    return HAVE_JAX and jax.default_backend() == "tpu"
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache for an entry point.
+
+    ``JAX_COMPILATION_CACHE_DIR``, when set, is JAX's own setting and
+    stands; otherwise the cache goes to ``<repo>/.jax_cache``.  Called
+    from scripts' ``__main__``, never at import.  Returns the directory."""
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        jax.config.update("jax_compilation_cache_dir", str(REPO_COMPILE_CACHE))
+    return str(jax.config.jax_compilation_cache_dir)
 
 
 def _next_pow2(n: int) -> int:
@@ -165,7 +169,6 @@ def _build_runner(
     rounds: int,
     record: bool,
     max_events: int,
-    use_pallas: bool,
     collect: bool,
     agg_levels: tuple,
     src_t: np.ndarray,
@@ -223,11 +226,27 @@ def _build_runner(
         tr_bw_out,  # [S, M] f64
         tr_slow,  # [S, M] f64
     ):
+        # Per-element lookups are select chains, not gathers: on a TPU v5e
+        # two gathers with slice sizes all 1 in the oes filling loop took
+        # 93% of the device time of the papers100M job (PERF.md), while M
+        # (or N) selects fuse into one elementwise pass on any backend.
+        # Exactly one branch matches, so the value is the same bits a
+        # gather would read.
+        def pick(idx, a2d):  # a2d[b, idx[b, c]] for idx in [0, a2d.shape[1])
+            out = jnp.broadcast_to(a2d[:, :1], idx.shape)
+            for m in range(1, a2d.shape[1]):
+                out = jnp.where(idx == m, a2d[:, m : m + 1], out)
+            return out
+
+        def pick_iter(a3d, idx):  # a3d[b, c, idx[b, c]] over the iteration axis
+            hit = jnp.arange(N, dtype=idx.dtype)[None, None, :] == idx[:, :, None]
+            return jnp.sum(jnp.where(hit, a3d, 0.0), axis=2)
+
         def gather_dst(a2d):  # [B, M] -> [B, EG] by dst machine
-            return jnp.take_along_axis(a2d, dst_mx, axis=1)
+            return pick(dst_mx, a2d)
 
         def gather_src(a2d):
-            return jnp.take_along_axis(a2d, src_mx, axis=1)
+            return pick(src_mx, a2d)
 
         # fixed per run: boolean NIC incidences laid out [B, M, EG] so
         # every per-machine reduction runs over the minor-most axis — XLA
@@ -325,17 +344,6 @@ def _build_runner(
                     mask, remaining / jnp.maximum(lim, EPS), jnp.inf
                 )
             order = jnp.argsort(key, axis=1)  # stable: ties by column
-            if use_pallas:
-                from ..kernels.waterfill import waterfill_fill
-
-                return waterfill_fill(
-                    order.astype(jnp.int32),
-                    src_mx.astype(jnp.int32),
-                    dst_mx.astype(jnp.int32),
-                    mask,
-                    cap_in,
-                    cap_out,
-                )
 
             def body(kk, carry):
                 r, rem_i, rem_o = carry
@@ -413,9 +421,11 @@ def _build_runner(
             if mode == "deadline" and dl_events:
                 lim = jnp.minimum(gather_dst(cap_in), gather_src(cap_out))
                 need = remaining / jnp.maximum(lim, EPS)
+                # + EPS: complement of the wake rule in advance(), as in
+                # engine._effective_classes
                 urgent = (
                     (flow_cls > CLASS_TRAINING)
-                    & ((flow_dl - t[:, None]) <= need)
+                    & ((flow_dl - t[:, None]) <= need + EPS)
                 )
                 eff = jnp.where(urgent, top_level, flow_cls)
                 level_list = (top_level,) + tuple(levels)
@@ -467,9 +477,7 @@ def _build_runner(
                 & (nxt <= last_eg[None, :])
                 & (src_done >= nxt)
             )
-            vn = jnp.take_along_axis(
-                vol, jnp.clip(nxt - 1, 0, N - 1)[:, :, None], axis=2
-            )[..., 0]
+            vn = pick_iter(vol, jnp.clip(nxt - 1, 0, N - 1))
             if no_cascade:  # statically no zero-volume instances anywhere
                 zero = None
                 arm = ready
@@ -502,11 +510,9 @@ def _build_runner(
                 & dep
                 & ~((ncand == 1) & (migleft > 0))
             )
-            exn = jnp.take_along_axis(
-                ex, jnp.clip(ncand - 1, 0, N - 1)[:, :, None], axis=2
-            )[..., 0]
+            exn = pick_iter(ex, jnp.clip(ncand - 1, 0, N - 1))
             if use_slow:
-                slow_t = jnp.take_along_axis(tr_slow[s.seg], y_mat, axis=1)
+                slow_t = pick(y_mat, tr_slow[s.seg])
                 end_new = t[:, None] + exn * slow_t
             else:  # no slowdowns anywhere in the trace: ex * 1.0 == ex
                 end_new = t[:, None] + exn
@@ -892,7 +898,7 @@ def simulate_batch_jax(
         Bp, E, Gmax, J, N, M, S, inner.name, mode, dl_events, use_slow,
         no_cascade, levels,
         int(getattr(inner, "rounds", 4)), record, max_events,
-        _use_pallas_waterfill(), bool(utilization), agg_levels,
+        bool(utilization), agg_levels,
         src_t.tobytes(), dst_t.tobytes(), lag.tobytes(),
     )
     runner = _runner_for(
@@ -903,7 +909,6 @@ def simulate_batch_jax(
             use_slow=use_slow, no_cascade=no_cascade,
             levels=levels, rounds=int(getattr(inner, "rounds", 4)),
             record=record, max_events=max_events,
-            use_pallas=_use_pallas_waterfill(),
             collect=bool(utilization), agg_levels=agg_levels,
             src_t=src_t, dst_t=dst_t, lag=lag,
         ),

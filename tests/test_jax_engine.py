@@ -19,7 +19,6 @@ Covered here:
     compiled out of easy workloads, so nothing else exercises this path);
   * backend routing: kwarg > REPRO_ENGINE_BACKEND env > numpy default,
     loud errors for unknown backends / missing jax / custom policies;
-  * the Pallas waterfill kernel vs the XLA fori_loop rate pass;
   * the per-backend ``plan()`` chain-count defaults (re-derived from the
     measured sweep in the ROADMAP perf log);
   * a hypothesis property sweep over random jobs (skipped when hypothesis
@@ -262,26 +261,37 @@ def test_float64_is_explicit(routing_case):
     assert isinstance(res.makespan, float)
 
 
-# ---------------------------------------------------------------------------
-# Pallas waterfill kernel vs the XLA fori_loop path
-# ---------------------------------------------------------------------------
-@pytest.mark.parametrize("policy", ("fifo", "mrtf"))
-def test_waterfill_pallas_matches_xla(matrix_case, policy, monkeypatch):
-    """REPRO_WATERFILL_PALLAS=1 swaps the sequential waterfill onto the
-    Pallas kernel (interpret mode off-TPU, Mosaic-fallback idiom); the
-    rates — and therefore whole schedules — must match the XLA path.  The
-    jit cache keys on the kernel choice, so both variants coexist."""
-    wl, cluster, placements, reals, dyn, migs = matrix_case
-    ref = simulate_batch_jax(wl, cluster, placements, reals, policy=policy,
-                             record=True, trace=dyn, migrations=migs)
-    monkeypatch.setenv("REPRO_WATERFILL_PALLAS", "1")
-    got = simulate_batch_jax(wl, cluster, placements, reals, policy=policy,
-                             record=True, trace=dyn, migrations=migs)
-    for b in range(3):
-        assert ref[b].makespan == got[b].makespan
-        sm_r = ref[b].task_start_matrix(wl.J, reals[0].n_iters)
-        sm_g = got[b].task_start_matrix(wl.J, reals[0].n_iters)
-        assert np.array_equal(sm_r, sm_g, equal_nan=True)
+@pytest.mark.parametrize("from_env", (True, False), ids=("env", "repo-default"))
+def test_compile_cache_lands_where_configured(from_env, tmp_path, monkeypatch):
+    """``enable_compile_cache``: ``JAX_COMPILATION_CACHE_DIR`` (which JAX
+    reads at start-up) stands when set; otherwise compiled programs land
+    in the fixed repository directory."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    env_dir, repo_dir = tmp_path / "env", tmp_path / "repo"
+    monkeypatch.setattr(engine_jax, "REPO_COMPILE_CACHE", repo_dir)
+    saved = {
+        k: getattr(jax.config, k)
+        for k in ("jax_compilation_cache_dir",
+                  "jax_persistent_cache_min_compile_time_secs")
+    }
+    if from_env:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(env_dir))
+        jax.config.update("jax_compilation_cache_dir", str(env_dir))
+    else:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    want = env_dir if from_env else repo_dir
+    try:
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+        compilation_cache.reset_cache()
+        assert engine_jax.enable_compile_cache() == str(want)
+        jax.jit(lambda x: x * 3.0 + 1.0)(np.arange(5.0 + from_env)).block_until_ready()
+        assert any(want.iterdir())
+        assert not (env_dir if not from_env else repo_dir).exists()
+    finally:
+        for k, v in saved.items():
+            jax.config.update(k, v)
+        compilation_cache.reset_cache()
 
 
 # ---------------------------------------------------------------------------

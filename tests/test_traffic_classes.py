@@ -234,6 +234,46 @@ def test_escalation_outranks_negative_qos_classes():
     assert eff[2] < eff[0] < eff[1]  # escalated above even the -2 job
 
 
+def test_escalation_fires_at_its_own_wake_time():
+    """Regression: the escalation wake is scheduled at
+    ``deadline - remaining / lim`` only while that lies beyond ``now +
+    EPS``, so the urgency test must take the same EPS.  With an exact
+    ``deadline - now <= need`` a flow woken at its own computed time missed
+    escalation on a rounding of ``deadline - (deadline - need)`` (about 4
+    draws in 10 below) and then had no wake left: it escalated only at the
+    next unrelated event and landed past its deadline."""
+    from repro.core.engine import _effective_classes
+
+    rng = np.random.default_rng(0)
+    one = np.zeros(1, dtype=np.int64)
+    cls = np.array([CLASS_MIGRATION], dtype=np.int64)
+    for _ in range(1000):
+        dl = rng.uniform(0.1, 10.0, 1)
+        rem = rng.uniform(0.01, 5.0, 1)
+        bw = rng.uniform(0.5, 12.5, 1)
+        wake = float(dl[0] - rem[0] / bw[0])
+        eff = _effective_classes("deadline", cls, dl, rem, one, one, bw, bw, wake)
+        assert eff[0] < CLASS_TRAINING
+
+
+@pytest.mark.parametrize("policy", ALL_POLICIES)
+def test_feasible_deadline_flows_land_at_their_deadline(policy):
+    """EDF certificate on the golden "priority" regime: the gated tail
+    move (deadline 3.0, feasible) lands AT its deadline on every golden job
+    under every base policy.  Before the wake/urgency EPS fix it landed up
+    to 0.8 s late wherever rounding skipped the escalation."""
+    from test_golden_schedules import _cases
+
+    for name, regime, wl, cluster, p, r, trace, flows, shaping in _cases():
+        if regime != "priority":
+            continue
+        res = simulate(wl, cluster, p, r, policy=policy, record=True,
+                       trace=trace, migrations=flows, shaping=shaping)
+        (tail_end,) = [t for e, _, _, t in res.flow_log if e == wl.E + 1]
+        assert flows[1].deadline == 3.0
+        assert tail_end == pytest.approx(3.0, abs=1e-6), name
+
+
 @pytest.mark.parametrize("policy", ALL_POLICIES)
 @pytest.mark.parametrize("mode", MODES)
 def test_batch_matches_scalar_shaped(policy, mode):
