@@ -21,6 +21,7 @@ from .placement import (
     ifs_placement,
 )
 from .workload import Realization, Workload
+from ..obs.spans import next_seq, span
 
 # Default ETP chain count per engine backend, re-derived from the measured
 # chain sweep (ROADMAP perf log; pinned by tests/test_jax_engine.py).
@@ -87,43 +88,49 @@ def plan(
     measured sweep in benchmarks/bench_engine.py).  The final committed
     schedule always runs on the reference numpy engine: it is ONE
     simulation, and its recorded ``flow_log`` feeds the audit artifacts."""
-    realization = realization or workload.realize(seed=seed)
-    backend = resolve_backend(backend)
-    if n_chains is None:
-        n_chains = DEFAULT_N_CHAINS[backend]
-    etp: Optional[ETPResult] = None
-    if search:
-        etp = etp_multichain(
-            workload,
-            cluster,
-            n_chains=n_chains,
-            budget=budget,
-            mu=mu,
-            beta=beta,
-            sim_iters=sim_iters,
-            seed=seed,
-            policy=policy,
-            time_budget_s=time_budget_s,
-            backend=backend,
-        )
-        placement = etp.placement
-    else:
-        placement = ifs_placement(workload, cluster, seed=seed)
-    # committed schedule: pinned to numpy even when REPRO_ENGINE_BACKEND=jax —
-    # the certificate's chain construction follows the recorded flow_log,
-    # which the jax engine does not produce (ONE simulation; never hot).
-    schedule = simulate(
-        workload, cluster, placement, realization, policy=policy, record=True,
-        backend="numpy",
-    )
-    cert = chain_lower_bound(workload, cluster, placement, realization, schedule)
+    with span("repro.plan", seq=next_seq(), budget=budget):
+        realization = realization or workload.realize(seed=seed)
+        backend = resolve_backend(backend)
+        if n_chains is None:
+            n_chains = DEFAULT_N_CHAINS[backend]
+        etp: Optional[ETPResult] = None
+        with span("repro.plan.search"):
+            if search:
+                etp = etp_multichain(
+                    workload,
+                    cluster,
+                    n_chains=n_chains,
+                    budget=budget,
+                    mu=mu,
+                    beta=beta,
+                    sim_iters=sim_iters,
+                    seed=seed,
+                    policy=policy,
+                    time_budget_s=time_budget_s,
+                    backend=backend,
+                )
+                placement = etp.placement
+            else:
+                placement = ifs_placement(workload, cluster, seed=seed)
+        # committed schedule: pinned to numpy even when REPRO_ENGINE_BACKEND=jax —
+        # the certificate's chain construction follows the recorded flow_log,
+        # which the jax engine does not produce (ONE simulation; never hot).
+        with span("repro.plan.commit.simulate"):
+            schedule = simulate(
+                workload, cluster, placement, realization, policy=policy,
+                record=True, backend="numpy",
+            )
+        with span("repro.plan.commit.audit"):
+            cert = chain_lower_bound(workload, cluster, placement, realization, schedule)
+            delta = max_degree(workload, placement, cluster)
+            traffic = traffic_summary(workload, placement, realization)
     return Plan(
         placement=placement,
         schedule=schedule,
         certificate=cert,
         etp=etp,
-        delta=max_degree(workload, placement, cluster),
-        traffic=traffic_summary(workload, placement, realization),
+        delta=delta,
+        traffic=traffic,
     )
 
 

@@ -46,7 +46,9 @@ policy, shaping levels, trace length, record, utilization).
 """
 from __future__ import annotations
 
+import hashlib
 import os
+import re
 from pathlib import Path
 from typing import (
     TYPE_CHECKING,
@@ -76,6 +78,8 @@ from .engine import (
     resolve_policy,
 )
 from .workload import Realization, Workload
+from ..obs import metrics as obs_metrics
+from ..obs.spans import span
 
 if TYPE_CHECKING:  # layering: core never imports dynamics at runtime
     from numpy.typing import ArrayLike
@@ -104,7 +108,24 @@ PARITY_ATOL = 1e-9
 
 JAX_POLICIES = ("oes", "oes_strict", "fifo", "mrtf", "omcoflow")
 
-_RUNNERS: Dict[tuple, object] = {}
+# the runner's device phases, as jax.named_scope names in its op_name
+# metadata (runner_scopes() reads them back); the lock-step loop's own
+# instruction (its self time is loop control) and the set-up before it
+# carry none
+SCOPES = ("settle", "rate_solve", "advance")
+
+
+class _Runner(NamedTuple):
+    fn: Callable[..., Any]  # the jitted lock-step program
+    rid: str  # short id of its cache key: repro.engine's ``runner`` argument
+    args: Tuple[Any, ...]  # ShapeDtypeStructs of its first call's arguments
+
+
+_RUNNERS: Dict[tuple, _Runner] = {}
+
+# an instruction of compiled HLO text, and the op_name of its metadata
+_INSTRUCTION = re.compile(r"^\s*(?:ROOT\s+)?(%\S+) = (.*)$", re.M)
+_OP_NAME = re.compile(r'metadata=\{[^}]*?op_name="([^"]*)"')
 
 # fixed, location-derived default for JAX's persistent compilation cache
 # (the directory is part of the cache key, so it must not move per run)
@@ -584,9 +605,11 @@ def _build_runner(
                 cap_out = jnp.broadcast_to(tr_bw_out[0], (B, M))
             # every rate rule returns 0 on inactive columns, so r > EPS
             # already implies active — no extra masking pass needed
-            r = compute_rates(
-                s.active, s.remaining, s.release, s.delivered, cap_in, cap_out, s.t
-            )
+            with jax.named_scope("rate_solve"):
+                r = compute_rates(
+                    s.active, s.remaining, s.release, s.delivered, cap_in,
+                    cap_out, s.t,
+                )
             dt = jnp.where(
                 r > EPS,
                 s.remaining / jnp.maximum(r, EPS),
@@ -686,14 +709,19 @@ def _build_runner(
             busy=jnp.zeros(agg_shape),
             clsgb=jnp.zeros(cls_shape),
         )
-        s = settle(s)
+        with jax.named_scope("settle"):
+            s = settle(s)
 
         def cond(s: _State) -> Any:
-            return (s.running.any() | s.active.any()) & (s.k < max_events)
+            # the batch-wide form of advance's per-row `alive` test
+            with jax.named_scope("advance"):
+                return (s.running.any() | s.active.any()) & (s.k < max_events)
 
         def body(s: _State) -> _State:
-            s = advance(s)
-            s = settle(s)
+            with jax.named_scope("advance"):
+                s = advance(s)
+            with jax.named_scope("settle"):
+                s = settle(s)
             return s._replace(k=s.k + 1)
 
         s = lax.while_loop(cond, body, s)
@@ -707,64 +735,71 @@ def _build_runner(
 
 
 def _runner_for(
-    key: Tuple[Any, ...], build_kwargs: Dict[str, Any]
-) -> Callable[..., Any]:
-    fn = _RUNNERS.get(key)
-    if fn is None:
-        fn = _build_runner(**build_kwargs)
-        _RUNNERS[key] = fn
-    return fn
+    key: Tuple[Any, ...], build_kwargs: Dict[str, Any], args: Sequence[np.ndarray]
+) -> _Runner:
+    r = _RUNNERS.get(key)
+    if r is None:
+        r = _Runner(
+            _build_runner(**build_kwargs),
+            hashlib.blake2s(repr(key).encode(), digest_size=4).hexdigest(),
+            tuple(jax.ShapeDtypeStruct(a.shape, a.dtype) for a in args),
+        )
+        _RUNNERS[key] = r
+        if obs_metrics.REGISTRY.enabled:
+            # a miss traces and compiles a new program on its first call
+            obs_metrics.REGISTRY.counter("engine.jax.runner_builds").inc()
+    return r
 
 
-def simulate_batch_jax(
+def _op_names(hlo_text: str) -> Dict[str, str]:
+    out = {}
+    for name, rest in _INSTRUCTION.findall(hlo_text):
+        m = _OP_NAME.search(rest)
+        out[name] = m.group(1) if m else ""
+    return out
+
+
+def runner_scopes() -> Dict[str, Dict[str, str]]:
+    """``{runner id: {HLO instruction name: op_name}}`` for every runner
+    built in this process: how an operator reads a TPU profile by phase.
+
+    A profile names each device operation by its instruction (``%while.37``,
+    ``%fusion.216``); its ``op_name`` holds the runner's named scopes
+    (``SCOPES``: ``.../settle/...``, ``.../advance/rate_solve/...``; ``""``
+    where the compiler left an instruction none), and the ``runner``
+    argument of the ``repro.engine`` span around the call names the runner.
+    Runners of other widths reuse instruction names for other work, so a
+    name is read against its own runner's map.  Each runner is lowered and
+    compiled again on its first call's argument shapes (a persistent-cache
+    hit where that cache is on), so call this after the profiled work, not
+    inside it."""
+    return {
+        r.rid: _op_names(r.fn.lower(*r.args).compile().as_text())
+        for r in _RUNNERS.values()
+    }
+
+
+def _assemble(
     workload: Workload,
     cluster: ClusterSpec,
     placements: Sequence[Placement],
     realizations: Sequence[Realization],
-    policy: "RatePolicy | str" = "oes",
-    record: bool = False,
-    max_events: int = 50_000_000,
-    trace: Optional["BandwidthTrace"] = None,
-    migrations: Optional[Sequence[Optional[Sequence[MigrationFlow]]]] = None,
-    shaping: Optional[str] = None,
-    edge_classes: Optional["ArrayLike"] = None,
-    utilization: bool = False,
-) -> List[ScheduleResult]:
-    """``engine.simulate_batch`` on the jitted JAX backend.
-
-    Same signature and event semantics; returns one ``ScheduleResult`` per
-    instance agreeing with the numpy engine at ``PARITY_RTOL`` (float64).
-    ``flow_log`` is always ``None`` (never recorded) and ``n_events``
-    counts jitted lock-step iterations — see the module docstring for the
-    exact contract.  ``utilization=True`` compiles the in-program
-    aggregate accumulators into the loop (its own jit cache entry) and
-    fills ``ScheduleResult.aggregates`` with per-machine NIC utilization
-    integrals (``nic_in_gb``/``nic_out_gb``), busy-time integrals
-    (``busy_s``) and per-class delivered bytes (``class_gb``) — the
-    observability substitute for the flow log this backend cannot afford.
-    """
-    if not HAVE_JAX:  # pragma: no cover
-        raise RuntimeError(
-            "backend='jax' requested but jax is not importable: "
-            f"{JAX_IMPORT_ERROR!r}"
-        )
-    policy = resolve_policy(policy, shaping)
+    policy: RatePolicy,
+    Bp: int,
+    *,
+    record: bool,
+    max_events: int,
+    trace: Optional["BandwidthTrace"],
+    migrations: Optional[Sequence[Optional[Sequence[MigrationFlow]]]],
+    edge_classes: Optional["ArrayLike"],
+    utilization: bool,
+) -> Tuple[Tuple[np.ndarray, ...], Tuple[Any, ...], Dict[str, Any]]:
+    """The runner's arguments, padded to ``Bp`` rows (repeating instance 0),
+    its cache key and its build arguments."""
     shaped = isinstance(policy, ShapedPolicy)
     inner = policy.base if shaped else policy
-    if inner.name not in JAX_POLICIES:
-        raise ValueError(
-            f"the jax engine backend supports the built-in rate policies "
-            f"{JAX_POLICIES}, got {inner.name!r} — use backend='numpy' for "
-            "custom policies"
-        )
     B = len(placements)
-    if B == 0:
-        return []
-    if len(realizations) != B:
-        raise ValueError("placements and realizations must have equal length")
     N = realizations[0].n_iters
-    if any(r.n_iters != N for r in realizations):
-        raise ValueError("all realizations in a batch must share n_iters")
     J, E, M = workload.J, workload.E, cluster.M
     src_t, dst_t, lag = workload.edge_src, workload.edge_dst, workload.edge_lag
 
@@ -874,7 +909,6 @@ def simulate_batch_jax(
 
     # pad the batch to a power of two (repeat instance 0) so the jit cache
     # sees a handful of widths; padding rows are discarded on return
-    Bp = _next_pow2(B)
     if Bp != B:
         pad = Bp - B
 
@@ -901,77 +935,148 @@ def simulate_batch_jax(
         bool(utilization), agg_levels,
         src_t.tobytes(), dst_t.tobytes(), lag.tobytes(),
     )
-    runner = _runner_for(
-        key,
-        dict(
-            B=Bp, E=E, Gmax=Gmax, J=J, N=N, M=M, S=S,
-            policy_name=inner.name, mode=mode, dl_events=dl_events,
-            use_slow=use_slow, no_cascade=no_cascade,
-            levels=levels, rounds=int(getattr(inner, "rounds", 4)),
-            record=record, max_events=max_events,
-            collect=bool(utilization), agg_levels=agg_levels,
-            src_t=src_t, dst_t=dst_t, lag=lag,
-        ),
+    build = dict(
+        B=Bp, E=E, Gmax=Gmax, J=J, N=N, M=M, S=S,
+        policy_name=inner.name, mode=mode, dl_events=dl_events,
+        use_slow=use_slow, no_cascade=no_cascade,
+        levels=levels, rounds=int(getattr(inner, "rounds", 4)),
+        record=record, max_events=max_events,
+        collect=bool(utilization), agg_levels=agg_levels,
+        src_t=src_t, dst_t=dst_t, lag=lag,
     )
-    t, nev, stuck, alive, start_rec, end_rec, util_in, util_out, busy, clsgb = runner(
+    args = (
         vol, ex, src_m, dst_m,
         ~local & (np.arange(EG) < E)[None, :],  # armable
         local[:, :E], flow_cls, flow_dl, gate_task, y_mat,
         delivered0, thresh0, remaining0, active0, migleft0,
         tr_times, tr_bw_in, tr_bw_out, tr_slow,
     )
-    t = np.asarray(t)[:B]
-    nev = np.asarray(nev)[:B]
-    stuck = np.asarray(stuck)[:B]
-    alive = np.asarray(alive)[:B]
-    if stuck.any():  # pragma: no cover - mirrors the numpy engine's guard
-        raise RuntimeError("no progress: flows active but zero rates")
-    if alive.any():  # pragma: no cover
-        raise RuntimeError("event limit exceeded — dependency deadlock?")
+    return args, key, build
 
-    out: List[ScheduleResult] = []
-    if record:
-        start_rec = np.asarray(start_rec)[:B]
-        end_rec = np.asarray(end_rec)[:B]
-    if utilization:
-        util_in = np.asarray(util_in)[:B]
-        util_out = np.asarray(util_out)[:B]
-        busy = np.asarray(busy)[:B]
-        clsgb = np.asarray(clsgb)[:B]
-    for b in range(B):
-        events: List[TaskEvent] = []
-        if record:
-            order = sorted(
-                (
-                    (start_rec[b, j, n], j, n)
-                    for j in range(J)
-                    for n in range(N)
-                    if not np.isnan(start_rec[b, j, n])
-                ),
-            )
-            events = [
-                TaskEvent(j, n + 1, float(st), float(end_rec[b, j, n]))
-                for st, j, n in order
-            ]
-        agg = None
-        if utilization:
-            agg = {
-                "nic_in_gb": util_in[b].copy(),
-                "nic_out_gb": util_out[b].copy(),
-                "busy_s": busy[b].copy(),
-                "class_gb": {
-                    lvl: float(clsgb[b, i])
-                    for i, lvl in enumerate(agg_levels)
-                },
-            }
-        out.append(
-            ScheduleResult(
-                makespan=float(t[b]),
-                task_events=events,
-                flow_log=None,
-                n_events=int(nev[b]),
-                policy=policy.name,
-                aggregates=agg,
-            )
+
+def simulate_batch_jax(
+    workload: Workload,
+    cluster: ClusterSpec,
+    placements: Sequence[Placement],
+    realizations: Sequence[Realization],
+    policy: "RatePolicy | str" = "oes",
+    record: bool = False,
+    max_events: int = 50_000_000,
+    trace: Optional["BandwidthTrace"] = None,
+    migrations: Optional[Sequence[Optional[Sequence[MigrationFlow]]]] = None,
+    shaping: Optional[str] = None,
+    edge_classes: Optional["ArrayLike"] = None,
+    utilization: bool = False,
+) -> List[ScheduleResult]:
+    """``engine.simulate_batch`` on the jitted JAX backend.
+
+    Same signature and event semantics; returns one ``ScheduleResult`` per
+    instance agreeing with the numpy engine at ``PARITY_RTOL`` (float64).
+    ``flow_log`` is always ``None`` (never recorded) and ``n_events``
+    counts jitted lock-step iterations — see the module docstring for the
+    exact contract.  ``utilization=True`` compiles the in-program
+    aggregate accumulators into the loop (its own jit cache entry) and
+    fills ``ScheduleResult.aggregates`` with per-machine NIC utilization
+    integrals (``nic_in_gb``/``nic_out_gb``), busy-time integrals
+    (``busy_s``) and per-class delivered bytes (``class_gb``) — the
+    observability substitute for the flow log this backend cannot afford.
+    """
+    if not HAVE_JAX:  # pragma: no cover
+        raise RuntimeError(
+            "backend='jax' requested but jax is not importable: "
+            f"{JAX_IMPORT_ERROR!r}"
         )
+    policy = resolve_policy(policy, shaping)
+    shaped = isinstance(policy, ShapedPolicy)
+    inner = policy.base if shaped else policy
+    if inner.name not in JAX_POLICIES:
+        raise ValueError(
+            f"the jax engine backend supports the built-in rate policies "
+            f"{JAX_POLICIES}, got {inner.name!r} — use backend='numpy' for "
+            "custom policies"
+        )
+    B = len(placements)
+    if B == 0:
+        return []
+    if len(realizations) != B:
+        raise ValueError("placements and realizations must have equal length")
+    N = realizations[0].n_iters
+    if any(r.n_iters != N for r in realizations):
+        raise ValueError("all realizations in a batch must share n_iters")
+    Bp = _next_pow2(B)
+    with span("repro.engine", width=B, padded=Bp) as eng:
+        with span("repro.engine.assemble"):
+            args, key, build = _assemble(
+                workload, cluster, placements, realizations, policy, Bp,
+                record=record, max_events=max_events, trace=trace,
+                migrations=migrations, edge_classes=edge_classes,
+                utilization=utilization,
+            )
+            runner = _runner_for(key, build, args)
+        eng.set_metadata(runner=runner.rid)
+        with span("repro.engine.dispatch"):
+            outs = runner.fn(*args)
+        with span("repro.engine.fetch"):
+            t, nev, stuck, alive = (np.asarray(a)[:B] for a in outs[:4])
+            start_rec, end_rec, util_in, util_out, busy, clsgb = outs[4:]
+            if record:
+                start_rec = np.asarray(start_rec)[:B]
+                end_rec = np.asarray(end_rec)[:B]
+            if utilization:
+                util_in = np.asarray(util_in)[:B]
+                util_out = np.asarray(util_out)[:B]
+                busy = np.asarray(busy)[:B]
+                clsgb = np.asarray(clsgb)[:B]
+        if stuck.any():  # pragma: no cover - mirrors the numpy engine's guard
+            raise RuntimeError("no progress: flows active but zero rates")
+        if alive.any():  # pragma: no cover
+            raise RuntimeError("event limit exceeded — dependency deadlock?")
+        with span("repro.engine.unpack"):
+            J = workload.J
+            agg_levels = build["agg_levels"]
+            out: List[ScheduleResult] = []
+            for b in range(B):
+                events: List[TaskEvent] = []
+                if record:
+                    order = sorted(
+                        (
+                            (start_rec[b, j, n], j, n)
+                            for j in range(J)
+                            for n in range(N)
+                            if not np.isnan(start_rec[b, j, n])
+                        ),
+                    )
+                    events = [
+                        TaskEvent(j, n + 1, float(st), float(end_rec[b, j, n]))
+                        for st, j, n in order
+                    ]
+                agg = None
+                if utilization:
+                    agg = {
+                        "nic_in_gb": util_in[b].copy(),
+                        "nic_out_gb": util_out[b].copy(),
+                        "busy_s": busy[b].copy(),
+                        "class_gb": {
+                            lvl: float(clsgb[b, i])
+                            for i, lvl in enumerate(agg_levels)
+                        },
+                    }
+                out.append(
+                    ScheduleResult(
+                        makespan=float(t[b]),
+                        task_events=events,
+                        flow_log=None,
+                        n_events=int(nev[b]),
+                        policy=policy.name,
+                        aggregates=agg,
+                    )
+                )
+    if obs_metrics.REGISTRY.enabled:
+        # once per call, pre-aggregated: the padded rows run on the device
+        # too, and the call's lock-step loop runs as long as its longest row
+        reg = obs_metrics.REGISTRY
+        reg.counter("engine.jax.calls").inc()
+        reg.counter("engine.jax.rows").inc(B)
+        reg.counter("engine.jax.padded_rows").inc(Bp - B)
+        reg.counter("engine.jax.lockstep_iters").inc(int(nev.max()))
     return out

@@ -53,6 +53,7 @@ from ..core.placement import ETPResult, etp_search, remap_after_leave
 from ..core.units import GB, Ratio, Seconds
 from ..core.workload import Workload
 from ..obs import metrics as obs_metrics
+from ..obs.spans import next_seq, span
 from .traces import relative_bw_drift
 
 
@@ -380,6 +381,24 @@ class Replanner:
         differentiate candidates by themselves, but they contend with
         both training traffic and discretionary moves, which is exactly
         what the analytic bill could not see.  Commits the winner."""
+        with span("repro.replan", seq=next_seq()):
+            return self._replan(
+                cluster_now, trigger=trigger, migration_free=migration_free,
+                budget=budget, amortize_over=amortize_over,
+                forced_restores=forced_restores,
+            )
+
+    def _replan(
+        self,
+        cluster_now: Optional[ClusterSpec],
+        *,
+        trigger: str,
+        migration_free: bool,
+        budget: Optional[int],
+        amortize_over: int,
+        forced_restores: Optional[Dict[int, int]],
+    ) -> ReplanRecord:
+        """``replan``'s body, inside the caller's ``repro.replan`` span."""
         cfg = self.config
         cluster_now = cluster_now or self.cluster
         incumbent = self.placement.copy()
@@ -499,45 +518,47 @@ class Replanner:
             # record still reports the signed physical delta
             return base + weight * max(0.0, overlap)
 
-        res = etp_search(
-            self.workload,
-            cluster_now,
-            budget=budget if budget is not None else cfg.budget,
-            seed=cfg.seed,
-            init=incumbent,
-            policy=cfg.policy,
-            sim_iters=cfg.sim_iters,
-            sim_draws=cfg.sim_draws,
-            cost_fn=objective,
-            extra_violation=extra,
-        )
+        with span("repro.replan.search"):
+            res = etp_search(
+                self.workload,
+                cluster_now,
+                budget=budget if budget is not None else cfg.budget,
+                seed=cfg.seed,
+                init=incumbent,
+                policy=cfg.policy,
+                sim_iters=cfg.sim_iters,
+                sim_draws=cfg.sim_draws,
+                cost_fn=objective,
+                extra_violation=extra,
+            )
         committed = res.placement
-        base, overlap, flows = side[committed.key()]
-        if flows and weight == 0.0:
-            # the objective never priced migration (migration_free): still
-            # report the physical overlap of whatever moves it chose
-            clean, loaded, flows = sim_pair(committed, flows)
-            overlap = loaded - clean
-        moved = (committed.y != old_y_disc) & (old_y_disc >= 0)
-        same_m = len(cluster_now.bw_in) == len(self._planned_bw_in)
-        rec = ReplanRecord(
-            trigger=trigger,
-            replanned=True,
-            # drift is undefined across a membership change (the machine
-            # sets differ); the trigger already names the cause there
-            drift=self.drift(cluster_now.bw_in, cluster_now.bw_out)
-            if same_m
-            else float("nan"),
-            moved_tasks=int(moved.sum()),
-            migration_gb=float(self.state_gb[moved].sum()),
-            forced_gb=float(sum(self.state_gb[j] for j in forced)),
-            migration_s=migration_drain_bound(cluster_now, flows),
-            overlap_s=float(overlap),
-            makespan=float(base),
-            objective=float(res.best_makespan),
-            flows=flows,
-            etp=res,
-        )
+        with span("repro.replan.price"):
+            base, overlap, flows = side[committed.key()]
+            if flows and weight == 0.0:
+                # the objective never priced migration (migration_free):
+                # still report the physical overlap of whatever moves it chose
+                clean, loaded, flows = sim_pair(committed, flows)
+                overlap = loaded - clean
+            moved = (committed.y != old_y_disc) & (old_y_disc >= 0)
+            same_m = len(cluster_now.bw_in) == len(self._planned_bw_in)
+            rec = ReplanRecord(
+                trigger=trigger,
+                replanned=True,
+                # drift is undefined across a membership change (the machine
+                # sets differ); the trigger already names the cause there
+                drift=self.drift(cluster_now.bw_in, cluster_now.bw_out)
+                if same_m
+                else float("nan"),
+                moved_tasks=int(moved.sum()),
+                migration_gb=float(self.state_gb[moved].sum()),
+                forced_gb=float(sum(self.state_gb[j] for j in forced)),
+                migration_s=migration_drain_bound(cluster_now, flows),
+                overlap_s=float(overlap),
+                makespan=float(base),
+                objective=float(res.best_makespan),
+                flows=flows,
+                etp=res,
+            )
         self.cluster = cluster_now
         self.placement = committed
         self._planned_bw_in = cluster_now.bw_in.copy()
@@ -601,21 +622,24 @@ class Replanner:
         forced restores, and naively billing them with pre-leave indices
         bincounts state against the wrong (or out-of-range) post-leave
         NICs — ``migration_time`` now refuses such stale indices loudly."""
-        old_y = self.placement.y.copy()  # pre-leave indices
-        m_old = self.cluster.M
-        new_cluster, warm = remap_after_leave(
-            self.workload, self.cluster, self.placement, machine
-        )
-        replica_pre = (machine + 1) % m_old
-        replica = replica_pre - 1 if replica_pre > machine else replica_pre
-        forced = {
-            int(j): replica for j in np.nonzero(old_y == machine)[0]
-        }
-        self.placement = warm
-        self._drop_cache_budget(machine)
-        return self.replan(
-            new_cluster, trigger="leave", forced_restores=forced
-        )
+        with span("repro.replan", seq=next_seq()):
+            with span("repro.replan.remap"):
+                old_y = self.placement.y.copy()  # pre-leave indices
+                m_old = self.cluster.M
+                new_cluster, warm = remap_after_leave(
+                    self.workload, self.cluster, self.placement, machine
+                )
+                replica_pre = (machine + 1) % m_old
+                replica = replica_pre - 1 if replica_pre > machine else replica_pre
+                forced = {
+                    int(j): replica for j in np.nonzero(old_y == machine)[0]
+                }
+                self.placement = warm
+                self._drop_cache_budget(machine)
+            return self._replan(
+                new_cluster, trigger="leave", migration_free=False,
+                budget=None, amortize_over=1, forced_restores=forced,
+            )
 
     def on_join(self, machine: Machine, *, cache_gb: float = 0.0) -> ReplanRecord:
         """Machine join: the incumbent stays valid (indices unchanged),

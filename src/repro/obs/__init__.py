@@ -1,18 +1,23 @@
 """Observability layer: schedule traces, blame attribution, exporters.
 
-Always available, off by default.  Three tiers:
+Always available, off by default.  Four tiers:
 
   * ``repro.obs.metrics`` — process-wide counters/gauges/histograms,
     gated by ``REPRO_OBS=1`` (no-ops otherwise; the engines' inner loops
     carry no obs code either way);
+  * ``repro.obs.spans`` — host spans on the profiler's clock
+    (``span(name, **args)``, a ``jax.profiler.TraceAnnotation``): they
+    record only while a profiler session is active, so an xprof trace of
+    ``plan()``, an engine call or a re-plan shows which phase the time
+    went to, beside the device's operations;
   * ``repro.obs.trace`` / ``repro.obs.blame`` — post-hoc analysis of a
     recorded schedule: task/flow spans, NIC utilization timelines,
     critical-path blame decomposition that conserves the makespan;
   * ``repro.obs.perfetto`` / ``repro.obs.telemetry`` — exporters:
     Chrome/Perfetto ``trace.json`` and planner telemetry dicts.
 
-``metrics`` is imported eagerly (it has no intra-repro dependencies and
-the core engines import it); the analysis modules load lazily on first
+``metrics`` and ``spans`` are imported eagerly (they have no intra-repro
+dependencies and the core engines import them); the analysis modules load lazily on first
 attribute access so ``repro.core -> repro.obs.metrics`` never cycles
 through ``repro.obs.trace -> repro.core``.
 """
@@ -22,6 +27,7 @@ import importlib
 from typing import Any
 
 from .metrics import REGISTRY, MetricsRegistry, enabled  # noqa: F401
+from .spans import next_seq, span  # noqa: F401
 
 _LAZY = {
     "ScheduleTrace": ("trace", "ScheduleTrace"),
@@ -40,7 +46,7 @@ _LAZY = {
     "cache_telemetry": ("telemetry", "cache_telemetry"),
 }
 
-__all__ = ["REGISTRY", "MetricsRegistry", "enabled", *_LAZY]
+__all__ = ["REGISTRY", "MetricsRegistry", "enabled", "next_seq", "span", *_LAZY]
 
 
 def __getattr__(name: str) -> Any:

@@ -97,6 +97,26 @@ def test_rl004_good_fixture_clean_under_engine_path():
     assert found == []
 
 
+def test_rl004_flags_host_spans_inside_hot_path_loops():
+    found = lint_fixture(
+        "rl004_span_bad.py",
+        rel_path="src/repro/core/engine_jax.py",
+        select=["RL004"],
+    )
+    assert len(found) == 2  # span() in a for-loop + TraceAnnotation in a while
+    assert rules_of(found) == ["RL004"]
+    assert all("host span" in f.message for f in found)
+
+
+def test_rl004_span_around_the_loop_is_clean():
+    found = lint_fixture(
+        "rl004_span_good.py",
+        rel_path="src/repro/core/engine.py",
+        select=["RL004"],
+    )
+    assert found == []
+
+
 def test_rl004_scoped_to_hot_paths_only():
     # same bad content under a non-engine path: rule does not apply
     found = lint_fixture(

@@ -333,17 +333,21 @@ class UnrecordedAccountingRule(Rule):
 class MetricsInHotLoopRule(Rule):
     """The obs contract (PR 7): call sites increment once per call with
     pre-aggregated values, never inside event loops — the <3% off-path
-    overhead pin in ``benchmarks/bench_obs.py`` depends on it.  Scoped to
-    the engine hot-path files.
+    overhead pin in ``benchmarks/bench_obs.py`` depends on it.  Host spans
+    (``repro.obs.spans.span``, ``TraceAnnotation``) follow the same
+    contract: one span per call, never one per event or instance.  Scoped
+    to the engine hot-path files.
     """
 
     rule_id = "RL004"
-    title = "REGISTRY/metrics calls inside engine hot-path loop bodies"
+    title = "REGISTRY/metrics calls or host spans inside engine hot-path loop bodies"
     rationale = (
-        "the obs off-path overhead pin (<3%, PR 7) holds because metrics "
-        "increment once per engine call, outside event loops — hoist the "
-        "call and pre-aggregate"
+        "the obs off-path overhead pin (<3%) holds because metrics "
+        "increment and spans open once per engine call, outside event "
+        "loops — hoist the call and pre-aggregate"
     )
+
+    SPAN_NAMES = {"span", "TraceAnnotation"}
 
     HOT_PATH_SUFFIXES = (
         "src/repro/core/engine.py",
@@ -385,6 +389,19 @@ class MetricsInHotLoopRule(Rule):
                                 "loop: hoist it out and increment once "
                                 "with a pre-aggregated value (obs "
                                 "overhead pin, PR 7)",
+                            )
+                        )
+                    elif names & self.SPAN_NAMES:
+                        for sub in ast.walk(node):
+                            if isinstance(sub, ast.Call):
+                                seen.add(id(sub))
+                        out.append(
+                            module.finding(
+                                self.rule_id,
+                                node,
+                                "host span opened inside an engine hot-path "
+                                "loop: open one span around the whole call "
+                                "(obs overhead pin)",
                             )
                         )
         return out
