@@ -16,7 +16,9 @@ numpy engine (the reference implementation):
   * all five built-in rate policies (oes / oes_strict / fifo / mrtf /
     omcoflow) are expressed as masked ``[B, EG]`` array programs over the
     per-instance ``[B, M]`` NIC capacity rows (the fifo/mrtf sequential
-    waterfill is a ``fori_loop`` over the priority order);
+    waterfill is a ``fori_loop`` over the priority order; oes's
+    progressive filling runs its rounds per NIC pair, on ``[B, M, M]``
+    counts of the flows from each machine to each machine);
   * ``ShapedPolicy`` class shaping is a statically unrolled loop over the
     run's concrete class levels (plus the EDF escalation level in
     deadline mode), each level rated against the leftovers of the levels
@@ -151,6 +153,8 @@ class _State(NamedTuple):
     k: object  # outer iteration counter (scalar)
     t: object  # [B] clock
     nev: object  # [B] lock-step iterations survived
+    fill: object  # oes filling rounds run, over iterations and class levels
+    #   (int32 scalar; the loop is batch-wide, so one count per call)
     stuck: object  # [B] zero-rate deadlock flag
     seg: object  # [B] trace segment pointer
     delivered: object  # [B, EG]
@@ -313,14 +317,36 @@ def _build_runner(
             # lock-step progressive filling, mirroring engine.oes_pool:
             # each instance raises its unfrozen flows by ITS OWN bottleneck
             # increment until a NIC saturates; frozen flows keep their level.
+            # A flow loads exactly two NICs, its dst machine's in-NIC and
+            # its src machine's out-NIC, so the flows of one (dst, src)
+            # machine pair are counted, frozen and raised together: the
+            # rounds run on [B, M, M] pair counts and [B, M] NIC state,
+            # never on the [B, EG] flow axis.  (M * M is below EG on every
+            # cluster the engine runs: 16 against 72 flows on the testbed,
+            # 256 against 1400 on papers100M's 16 machines; it would pass EG
+            # only on clusters of 64 machines or more.)
+            # pairs[b, i, o]: masked flows into machine i from machine o.
+            # One batched matmul, exact in float32 for counts up to EG: on a
+            # TPU v5e an integer sum of the [B, M, M, EG] conjunction in its
+            # place doubled the device time of a papers100M call.
+            pairs = jnp.einsum(
+                "bie,boe->bio",
+                oh_dst.astype(jnp.float32),
+                (oh_src & mask[:, None, :]).astype(jnp.float32),
+                precision=lax.Precision.HIGHEST,
+            )
+
             def cond(c):
-                flows = c[5]
-                return flows.any() & (c[6] < 4 * M)
+                flows = c[4]
+                return flows.any() & (c[5] < 4 * M)
 
             def body(c):
-                r, rem_i, rem_o, unfrozen, live, flows, k = c
-                cnt_i = cnt_dst(flows)
-                cnt_o = cnt_src(flows)
+                # flows: unfrozen pairs of instances still filling (a
+                # finished instance has none); lv_*: each NIC's level, the
+                # sum of the increments of the rounds it had unfrozen flows
+                lv_i, lv_o, rem_i, rem_o, flows, k = c
+                cnt_i = jnp.sum(jnp.where(flows, pairs, 0.0), axis=2).astype(jnp.float64)
+                cnt_o = jnp.sum(jnp.where(flows, pairs, 0.0), axis=1).astype(jnp.float64)
                 inc_i = jnp.min(
                     jnp.where(cnt_i > 0, rem_i / jnp.maximum(cnt_i, 1.0), jnp.inf),
                     axis=1,
@@ -330,31 +356,42 @@ def _build_runner(
                     axis=1,
                 )
                 inc_b = jnp.minimum(inc_i, inc_o)
-                live = live & jnp.isfinite(inc_b)
-                flows = flows & live[:, None]
-                r = r + jnp.where(flows, inc_b[:, None], 0.0)
-                inc_f = jnp.where(live, inc_b, 0.0)
+                inc_f = jnp.where(jnp.isfinite(inc_b), inc_b, 0.0)
+                lv_i = lv_i + jnp.where(cnt_i > 0, inc_b[:, None], 0.0)
+                lv_o = lv_o + jnp.where(cnt_o > 0, inc_b[:, None], 0.0)
                 rem_i = rem_i - inc_f[:, None] * cnt_i
                 rem_o = rem_o - inc_f[:, None] * cnt_o
                 sat_i = (rem_i <= EPS) & (cnt_i > 0)
                 sat_o = (rem_o <= EPS) & (cnt_o > 0)
-                newly = flows & (gather_dst(sat_i) | gather_src(sat_o))
-                live = live & newly.any(axis=1)
-                unfrozen = unfrozen & ~newly
-                flows = unfrozen & live[:, None]
-                return r, rem_i, rem_o, unfrozen, live, flows, k + 1
+                # a round that saturates no NIC ends the instance's filling
+                live = sat_i.any(axis=1) | sat_o.any(axis=1)
+                flows = (
+                    flows
+                    & ~(sat_i[:, :, None] | sat_o[:, None, :])
+                    & live[:, None, None]
+                )
+                return lv_i, lv_o, rem_i, rem_o, flows, k + 1
 
             init = (
-                jnp.zeros((B, EG)),
+                jnp.zeros((B, M)),
+                jnp.zeros((B, M)),
                 cap_in,
                 cap_out,
-                mask,
-                jnp.ones(B, dtype=bool),
-                mask,
-                jnp.int64(0),
+                pairs > 0,
+                jnp.int32(0),
             )
-            r = lax.while_loop(cond, body, init)[0]
-            return jnp.where(mask, r, 0.0)
+            lv_i, lv_o, _, _, _, rounds = lax.while_loop(cond, body, init)
+            # a pair rises until the first of its two NICs stops, and levels
+            # only grow, so its level (its flows' rate) is the smaller of its
+            # NICs' levels: the same sums, bit for bit.  The lookup is a
+            # one-hot max over the minor M axis (levels are >= 0): one fused
+            # pass, where a select chain slices M columns in M passes.
+            ms = jnp.arange(M, dtype=dst_mx.dtype)[None, None, :]
+            r = jnp.minimum(
+                jnp.max(jnp.where(dst_mx[:, :, None] == ms, lv_i[:, None, :], 0.0), axis=2),
+                jnp.max(jnp.where(src_mx[:, :, None] == ms, lv_o[:, None, :], 0.0), axis=2),
+            )
+            return jnp.where(mask, r, 0.0), rounds
 
         def rates_waterfill(mask, cap_in, cap_out, remaining, release, grp):
             if policy_name == "fifo":
@@ -421,13 +458,19 @@ def _build_runner(
             r = lax.fori_loop(0, rounds, rnd, r)
             return jnp.where(mask, r, 0.0)
 
-        base = {
+        rates = {
             "oes": rates_oes,
             "oes_strict": rates_oes_strict,
             "fifo": rates_waterfill,
             "mrtf": rates_waterfill,
             "omcoflow": rates_omcoflow,
         }[policy_name]
+
+        def base(*args):
+            """(rates, filling rounds): only oes fills in rounds."""
+            if policy_name == "oes":
+                return rates(*args)
+            return rates(*args), jnp.int32(0)
 
         def compute_rates(active, remaining, release, delivered, cap_in, cap_out, t):
             grp = None
@@ -456,15 +499,17 @@ def _build_runner(
             if len(level_list) == 1:
                 return base(active, cap_in, cap_out, remaining, release, grp)
             r = jnp.zeros((B, EG))
+            rounds = jnp.int32(0)
             rem_i, rem_o = cap_in, cap_out
             for c in level_list:
                 m = active & (eff == c)
-                sub = base(m, rem_i, rem_o, remaining, release, grp)
+                sub, n = base(m, rem_i, rem_o, remaining, release, grp)
                 r = jnp.where(m, sub, r)
+                rounds = rounds + n
                 sm = jnp.where(m, sub, 0.0)
                 rem_i = jnp.maximum(rem_i - sum_dst(sm), 0.0)
                 rem_o = jnp.maximum(rem_o - sum_src(sm), 0.0)
-            return r
+            return r, rounds
 
         # ---- settle: fixpoint of same-instant completions/arms/starts ----
         def settle_round(s: _State) -> _State:
@@ -606,7 +651,7 @@ def _build_runner(
             # every rate rule returns 0 on inactive columns, so r > EPS
             # already implies active — no extra masking pass needed
             with jax.named_scope("rate_solve"):
-                r = compute_rates(
+                r, rounds = compute_rates(
                     s.active, s.remaining, s.release, s.delivered, cap_in,
                     cap_out, s.t,
                 )
@@ -675,6 +720,7 @@ def _build_runner(
             return s._replace(
                 t=t,
                 nev=s.nev + adv.astype(jnp.int64),
+                fill=s.fill + rounds,
                 stuck=s.stuck | bad,
                 seg=seg,
                 remaining=remaining,
@@ -691,6 +737,7 @@ def _build_runner(
             k=jnp.int64(0),
             t=jnp.zeros(B),
             nev=jnp.zeros(B, dtype=jnp.int64),
+            fill=jnp.int32(0),
             stuck=jnp.zeros(B, dtype=bool),
             seg=jnp.zeros(B, dtype=jnp.int32),
             delivered=delivered0,
@@ -728,7 +775,7 @@ def _build_runner(
         alive = s.running.any(axis=1) | s.active.any(axis=1)
         return (
             s.t, s.nev, s.stuck, alive, s.start_rec, s.end_rec,
-            s.util_in, s.util_out, s.busy, s.clsgb,
+            s.util_in, s.util_out, s.busy, s.clsgb, s.fill,
         )
 
     return jax.jit(run)
@@ -1015,7 +1062,7 @@ def simulate_batch_jax(
             runner = _runner_for(key, build, args)
         eng.set_metadata(runner=runner.rid)
         with span("repro.engine.dispatch"):
-            outs = runner.fn(*args)
+            *outs, fill = runner.fn(*args)
         with span("repro.engine.fetch"):
             t, nev, stuck, alive = (np.asarray(a)[:B] for a in outs[:4])
             start_rec, end_rec, util_in, util_out, busy, clsgb = outs[4:]
@@ -1079,4 +1126,5 @@ def simulate_batch_jax(
         reg.counter("engine.jax.rows").inc(B)
         reg.counter("engine.jax.padded_rows").inc(Bp - B)
         reg.counter("engine.jax.lockstep_iters").inc(int(nev.max()))
+        reg.counter("engine.jax.fill_rounds").inc(int(fill))
     return out

@@ -22,14 +22,21 @@ Covered here:
   * the per-backend ``plan()`` chain-count defaults (re-derived from the
     measured sweep in the ROADMAP perf log);
   * a hypothesis property sweep over random jobs (skipped when hypothesis
-    is not installed).
+    is not installed);
+  * the oes runner's own outputs pinned bit for bit against
+    tests/golden/jax_oes_schedules.json (the golden jobs in every regime,
+    and a testbed batch of width 16), and the ``engine.jax.fill_rounds``
+    counter of its filling rounds.
 
-``n_events`` is NOT compared anywhere: the jax engine counts lock-step
+``n_events`` is never compared with numpy's: the jax engine counts lock-step
 iterations (zero-duration cascades settle inside one), a documented
 divergence.  ``flow_log`` is ``None`` on the jax backend (never
 recorded, distinct from numpy's recorded-but-empty ``[]``);
 ``task_events`` are exact and are what the start-matrix checks consume.
 """
+import contextlib
+import json
+
 import numpy as np
 import pytest
 
@@ -37,19 +44,25 @@ jax = pytest.importorskip("jax")
 
 from repro.core import (
     ENGINE_BACKENDS,
+    ClusterSpec,
+    Machine,
     MigrationFlow,
+    Placement,
     build_gnn_workload,
     heterogeneous_cluster,
     ifs_placement,
     resolve_backend,
     simulate,
     simulate_batch,
+    testbed_cluster,
 )
 from repro.core.dgtp import DEFAULT_N_CHAINS, plan
 from repro.core.engine import OESRate, RatePolicy
 from repro.core import engine_jax
 from repro.core.engine_jax import PARITY_ATOL, PARITY_RTOL, simulate_batch_jax
+from repro.core.profiles import OGBN_PRODUCTS, build_workload_from_profile
 from repro.dynamics import DynamicsEvent, trace_from_events
+from repro.obs import REGISTRY
 
 from test_golden_schedules import GOLDEN_PATH, JOBS, REGIMES, _cases
 
@@ -148,8 +161,6 @@ def test_cascade_settle_parity(policy):
 # ---------------------------------------------------------------------------
 @pytest.fixture(scope="module")
 def golden():
-    import json
-
     assert GOLDEN_PATH.exists()
     return json.loads(GOLDEN_PATH.read_text())
 
@@ -370,3 +381,206 @@ def test_parity_property():
     hypothesis.given(
         seed=st.integers(0, 10_000), policy=st.sampled_from(POLICIES)
     )(hypothesis.settings(max_examples=8, deadline=None)(_parity_property))()
+
+
+# ---------------------------------------------------------------------------
+# the oes runner pinned bit for bit (tests/golden/jax_oes_schedules.json)
+# ---------------------------------------------------------------------------
+# Unlike the parity checks above, these are the jax engine's OWN exact
+# outputs: a change to the oes rate solve that moves one schedule by one
+# ULP, one lock-step iteration or one filling round fails here.
+# Regenerate (ONLY for an intended change, with the diff reviewed):
+#   PYTHONPATH=src python tests/test_jax_engine.py --regen <case...>
+# Cases already pinned are NEVER overwritten unless named; a bare --regen
+# only fills in missing ones.
+JAX_OES_PATH = GOLDEN_PATH.parent / "jax_oes_schedules.json"
+TESTBED_WIDTH = 16
+
+
+def _testbed_batch():
+    """The paper's 4-server testbed job (products, 4 iterations) at width
+    16: seeded random placements, so the batch holds many NIC-pair mixes."""
+    wl = build_workload_from_profile(
+        OGBN_PRODUCTS, n_stores=4, n_workers=6, samplers_per_worker=2,
+        n_ps=1, n_iters=4,
+    )
+    cluster = testbed_cluster()
+    rng = np.random.default_rng(0)
+    placements = [Placement(rng.integers(0, cluster.M, size=wl.J))
+                  for _ in range(TESTBED_WIDTH)]
+    reals = [wl.realize(seed=s) for s in range(TESTBED_WIDTH)]
+    return wl, cluster, placements, reals
+
+
+def _oes_cases():
+    """{case id: (workload, cluster, placements, realizations, kwargs)}:
+    the golden jobs in every regime at width 1, and the testbed batch."""
+    out = {}
+    for (name, regime, wl, cluster, placement, realization, trace, flows,
+         shaping) in _cases():
+        out[f"{name}-{regime}"] = (
+            wl, cluster, [placement], [realization],
+            dict(trace=trace, shaping=shaping,
+                 migrations=None if flows is None else [flows]),
+        )
+    wl, cluster, placements, reals = _testbed_batch()
+    out[f"testbed-w{TESTBED_WIDTH}"] = (wl, cluster, placements, reals, {})
+    return out
+
+
+@contextlib.contextmanager
+def _registry_on():
+    was = REGISTRY.enabled
+    REGISTRY.enable()
+    REGISTRY.reset()
+    try:
+        yield REGISTRY
+    finally:
+        REGISTRY.enabled = was
+        REGISTRY.reset()
+
+
+def _run_counted(wl, cluster, placements, reals, policy="oes", **kw):
+    """The call's results and its ``engine.jax.*`` counters."""
+    with _registry_on() as reg:
+        res = simulate_batch_jax(wl, cluster, placements, reals,
+                                 policy=policy, record=True, **kw)
+        snap = reg.snapshot()
+    return res, {k: v["value"] for k, v in snap.items()}
+
+
+def _oes_record(case):
+    wl, cluster, placements, reals, kw = case
+    res, counters = _run_counted(wl, cluster, placements, reals, **kw)
+    n_iters = reals[0].n_iters
+    return {
+        "fill_rounds": int(counters["engine.jax.fill_rounds"]),
+        "results": [
+            {
+                "makespan": r.makespan,
+                "n_events": r.n_events,
+                "task_start": r.task_start_matrix(wl.J, n_iters).tolist(),
+            }
+            for r in res
+        ],
+    }
+
+
+def regen_jax_oes(named=(), path=JAX_OES_PATH):
+    """The pinned file with missing cases (and those ``named``) simulated
+    anew; every other case is kept byte for byte."""
+    cases = _oes_cases()
+    unknown = set(named) - set(cases)
+    if unknown:
+        raise ValueError(f"unknown case(s) {sorted(unknown)}; known: {sorted(cases)}")
+    existing = json.loads(path.read_text()) if path.exists() else {}
+    return {
+        cid: (_oes_record(case) if cid in named or cid not in existing
+              else existing[cid])
+        for cid, case in cases.items()
+    }
+
+
+@pytest.fixture(scope="module")
+def jax_oes_pinned():
+    assert JAX_OES_PATH.exists()
+    return json.loads(JAX_OES_PATH.read_text())
+
+
+@pytest.fixture(scope="module")
+def oes_cases():
+    return _oes_cases()
+
+
+OES_CASE_IDS = [f"{n}-{r}" for n in JOBS for r in REGIMES] + [f"testbed-w{TESTBED_WIDTH}"]
+
+
+@pytest.mark.parametrize("case_id", OES_CASE_IDS)
+def test_jax_oes_pinned_bit_for_bit(jax_oes_pinned, oes_cases, case_id):
+    """Makespan, lock-step iterations, every task start and the filling
+    rounds of the call equal the pinned values exactly."""
+    want = jax_oes_pinned[case_id]
+    got = _oes_record(oes_cases[case_id])
+    assert got["fill_rounds"] == want["fill_rounds"]
+    assert len(got["results"]) == len(want["results"])
+    for b, (g, w) in enumerate(zip(got["results"], want["results"])):
+        assert g["makespan"] == w["makespan"], (case_id, b)
+        assert g["n_events"] == w["n_events"], (case_id, b)
+        assert np.array_equal(np.asarray(g["task_start"]),
+                              np.asarray(w["task_start"])), (case_id, b)
+
+
+def test_jax_oes_pins_cover_every_case(jax_oes_pinned):
+    assert sorted(jax_oes_pinned) == sorted(OES_CASE_IDS)
+
+
+# ---------------------------------------------------------------------------
+# engine.jax.fill_rounds: the oes filling loop's rounds, summed per call
+# ---------------------------------------------------------------------------
+def _two_machine_cluster(bw):
+    return ClusterSpec(machines=[
+        Machine(name=f"m{i}", resources={"mem": 64.0, "cpu": 16.0, "gpu": 2.0},
+                bw_in=bw, bw_out=bw)
+        for i in range(2)
+    ])
+
+
+def test_fill_rounds_one_round_per_solve_on_one_nic_pair():
+    """Stores on machine 1, every other task on machine 0: every training
+    flow and a long ungated migration flow run 1 -> 0, so each rate solve
+    freezes all its flows in its first round, and the migration flow keeps
+    a flow active in every lock-step iteration."""
+    wl = build_gnn_workload(
+        n_stores=2, n_workers=1, samplers_per_worker=2, n_ps=1, n_iters=3,
+        store_to_sampler_gb=0.5, sampler_to_worker_gb=0.3, grad_gb=0.2,
+        store_exec_s=0.3, sampler_exec_s=0.4, worker_exec_s=0.8,
+        ps_exec_s=0.2,
+    )
+    cluster = _two_machine_cluster(1.25)
+    y = np.array([1 if t.name.startswith("store") else 0 for t in wl.tasks])
+    reals = [wl.realize(seed=s) for s in range(3)]
+    flows = [MigrationFlow(src=1, dst=0, gb=50.0)]
+    res, counters = _run_counted(wl, cluster, [Placement(y)] * 3, reals,
+                                 migrations=[flows] * 3)
+    iters = counters["engine.jax.lockstep_iters"]
+    assert iters == max(r.n_events for r in res) > 3 * 3
+    assert counters["engine.jax.fill_rounds"] == iters
+
+
+@pytest.mark.parametrize("shaping,levels", ((None, 1), ("strict", 2)))
+def test_fill_rounds_count_every_class_level(shaping, levels):
+    """Every task on machine 0 and two migration flows of equal volume on
+    disjoint NICs (0 -> 1 and 1 -> 0) in two traffic classes: each flow
+    saturates its own pair in one round and both run to the last
+    iteration.  Unshaped, one solve serves both; under class shaping each
+    class level runs its own filling loop, and the counter sums them."""
+    wl = build_gnn_workload(
+        n_stores=1, n_workers=1, samplers_per_worker=1, n_ps=1, n_iters=2,
+        store_to_sampler_gb=0.5, sampler_to_worker_gb=0.3, grad_gb=0.2,
+        store_exec_s=0.3, sampler_exec_s=0.4, worker_exec_s=0.8,
+        ps_exec_s=0.2,
+    )
+    cluster = _two_machine_cluster(2.5)
+    flows = [MigrationFlow(src=0, dst=1, gb=20.0, cls=1),
+             MigrationFlow(src=1, dst=0, gb=20.0, cls=2)]
+    res, counters = _run_counted(
+        wl, cluster, [Placement(np.zeros(wl.J, dtype=np.int64))],
+        [wl.realize(seed=0)], migrations=[flows], shaping=shaping,
+    )
+    iters = counters["engine.jax.lockstep_iters"]
+    assert iters == res[0].n_events > 2
+    assert res[0].makespan == pytest.approx(20.0 / 2.5)
+    assert counters["engine.jax.fill_rounds"] == levels * iters
+
+
+if __name__ == "__main__":
+    import sys
+
+    if "--regen" in sys.argv:
+        named = [a for a in sys.argv[sys.argv.index("--regen") + 1:]
+                 if not a.startswith("-")]
+        pinned = regen_jax_oes(named)
+        JAX_OES_PATH.write_text(json.dumps(pinned, indent=1) + "\n")
+        print(f"wrote {JAX_OES_PATH}: {sorted(pinned)}")
+    else:
+        print(__doc__)

@@ -156,6 +156,9 @@ def test_engine_counters_count_calls_rows_and_builds(fresh_runners, monkeypatch)
         REGISTRY.enabled = was
         REGISTRY.reset()
     value = {k: v["value"] for k, v in snap.items() if k.startswith("engine.jax.")}
+    # the oes filling loop runs at most 4 * M rounds a lock-step iteration
+    rounds = value.pop("engine.jax.fill_rounds")
+    assert 0 < rounds <= 4 * cluster.M * value["engine.jax.lockstep_iters"]
     assert value == {
         "engine.jax.calls": 3,
         "engine.jax.rows": 7,

@@ -21,7 +21,7 @@ jax = pytest.importorskip("jax")
 
 import chip_smoke
 from repro.core import engine_jax
-from repro.core.engine_jax import JAX_POLICIES, simulate_batch_jax
+from repro.core.engine_jax import _INSTRUCTION, JAX_POLICIES, simulate_batch_jax
 
 HBM_BYTES = 16 * 1024**3  # one TPU v5e chip
 
@@ -128,3 +128,17 @@ def test_papers100m_wide_runner_compiles_for_v5e(compile_for_chip):
     _check(compile_for_chip)
     (exe,) = compile_for_chip
     assert not re.search(r"slice_sizes=\{1(,1)*\}", exe.as_text())
+    # the oes filling rounds run on [B, M, M] NIC-pair counts: no
+    # instruction of the filling loop's body reads or writes a per-flow
+    # array (a dimension of E = 1400 flows; no other axis is that long)
+    E = wl.E
+    assert E not in (len(placements), cluster.M, wl.J, reals[0].n_iters)
+    body = [
+        line for name, line in _INSTRUCTION.findall(exe.as_text())
+        if "/rate_solve/while/body/" in engine_jax._op_names(f"{name} = {line}")[name]
+    ]
+    assert body
+    for line in body:
+        dims = [int(d) for shape in re.findall(r"\w\[([\d,]+)\]", line.split(", metadata=")[0])
+                for d in shape.split(",")]
+        assert E not in dims, line
