@@ -3,7 +3,8 @@
     python chipbench/control.py --workload <cell> --seconds <s> --seeds <n> [<n> ...]
 
 For each seed, one run of the cell as ``run.py`` makes it, except that the
-comparison puts the reference computed in float32 in the program's place.
+comparison puts the deployment's reference, computed in float32, in the
+program's place.
 Every compared number must then read above its limit (``correct`` false).
 All seeds run in one process, so the engine compiles once.  The program's
 own readings come from ordinary runs of ``run.py``.

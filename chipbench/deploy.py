@@ -1,36 +1,48 @@
 """Deployments: a cluster and a training job, built from a configuration file.
 
 A configuration (``chipbench/configs/<name>.json``) holds the numbers of one
-deployment from the paper: the machines, the dataset statistics the job's
-traffic is derived from, and the job's task counts.  This module turns them
-into
+deployment: the machines, the statistics the job's traffic is derived from,
+and the job's task counts.  Its optional ``"kind"`` (default ``gnn_ps``)
+names the module ``chipbench/deployments/<kind>.py`` that builds it: the
+job layout, the cluster and the plain reference are the kind's.  A kind's
+``load(name, cfg)`` returns a :class:`Deployment`:
 
 * a :class:`Job`, the benchmark's own description of the task/flow DAG and
-  its traffic model, which the reference simulator (``reference.py``) runs;
+  its traffic model;
 * the program's ``Workload`` and ``ClusterSpec``, built through the public
-  constructors of ``repro.core`` and checked against the :class:`Job` edge by
-  edge, so that the reference and the program run the same job.
+  constructors of ``repro.core`` and checked against the :class:`Job`, so
+  that the reference and the program run the same job;
+* everything else the operations (``ops.py``) need of the deployment,
+  so that no operation reads the configuration or names a reference
+  itself.
 
-The generators (the DAG layout, the volume derivation, the per-iteration
-draws and the cluster draw) are copies kept with the benchmark, so that a
-change to the program cannot move the yardstick.
+A new kind of deployment joins the benchmark through a new kind module, a
+configuration that names it, and traffic files: no file of the harness
+changes.
 """
 from __future__ import annotations
 
+import importlib.util
 import json
+import re
 from dataclasses import dataclass
 from pathlib import Path
-from typing import Any, Dict, List, Optional, Sequence, Tuple
+from types import ModuleType
+from typing import Any, Callable, List, Sequence, Tuple
 
 import numpy as np
+
+DEFAULT_KIND = "gnn_ps"
+
+Draws = Sequence[Tuple[np.ndarray, np.ndarray]]  # [(volumes [E, N], exec times [J, N])]
+
 
 @dataclass
 class Job:
     """Task/flow DAG and traffic model of one job (paper Sec. III).
 
-    Per iteration: store -> sampler (lag 0), sampler -> its worker (lag 0),
-    worker -> PS (lag 0), PS -> worker (lag 1).  Instance ``n`` of an edge
-    carries ``(src, n)``'s output to ``(dst, n + lag)``."""
+    Instance ``n`` of an edge carries ``(src, n)``'s output to
+    ``(dst, n + lag)``."""
 
     kinds: List[str]  # [J]
     src: np.ndarray  # [E] int
@@ -41,8 +53,6 @@ class Job:
     fluctuating: np.ndarray  # [E] bool
     pmr: float
     exec_jitter: float
-    demands: Dict[str, Dict[str, float]]
-    sampler_of_worker: Dict[int, List[int]]
 
     @property
     def J(self) -> int:
@@ -75,151 +85,46 @@ class Job:
         return [self.realize(seed + 1000 * d, n_iters) for d in range(n_draws)]
 
 
-def sampler_volume_gb(job: Dict[str, Any]) -> float:
-    """Graph data one sampler receives per iteration: seeds x sampled
-    nodes per seed (L-hop fan-out, deduplicated) x 4-byte features."""
-    total, width = 1.0, 1.0
-    for f in job["fanout"]:
-        width *= f
-        total += width
-    nodes_per_seed = total * job["unique_factor"]
-    seeds = job["batch_size"] // job["samplers_per_worker"]
-    return seeds * nodes_per_seed * job["feature_len"] * 4 / 2**30
-
-
-def build_job(cfg: Dict[str, Any]) -> Job:
-    j = cfg["job"]
-    kinds: List[str] = []
-    stores = [len(kinds) + i for i in range(j["n_stores"])]
-    kinds += ["store"] * j["n_stores"]
-    workers = [len(kinds) + i for i in range(j["n_workers"])]
-    kinds += ["worker"] * j["n_workers"]
-    sampler_of_worker: Dict[int, List[int]] = {}
-    for w in workers:
-        sampler_of_worker[w] = [len(kinds) + i for i in range(j["samplers_per_worker"])]
-        kinds += ["sampler"] * j["samplers_per_worker"]
-    samplers = [s for w in workers for s in sampler_of_worker[w]]
-    pss = [len(kinds) + i for i in range(j["n_ps"])]
-    kinds += ["ps"] * j["n_ps"]
-
-    vol_s = sampler_volume_gb(j)
-    edges: List[Tuple[int, int, int, float, bool]] = []
-    for s in samplers:
-        for g in stores:
-            edges.append((g, s, 0, vol_s / len(stores), True))
-    for w in workers:
-        for s in sampler_of_worker[w]:
-            edges.append((s, w, 0, vol_s, True))
-    for w in workers:
-        for p in pss:
-            edges.append((w, p, 0, j["grad_gb"] / len(pss), False))
-    for p in pss:
-        for w in workers:
-            edges.append((p, w, 1, j["grad_gb"] / len(pss), False))
-    exec_of = {
-        "store": j["store_exec_s"], "sampler": j["sampler_exec_s"],
-        "worker": j["worker_exec_s"], "ps": j["ps_exec_s"],
-    }
-    return Job(
-        kinds=kinds,
-        src=np.array([e[0] for e in edges], dtype=np.int64),
-        dst=np.array([e[1] for e in edges], dtype=np.int64),
-        lag=np.array([e[2] for e in edges], dtype=np.int64),
-        mean_volume=np.array([e[3] for e in edges], dtype=np.float64),
-        mean_exec=np.array([exec_of[k] for k in kinds], dtype=np.float64),
-        fluctuating=np.array([e[4] for e in edges], dtype=bool),
-        pmr=float(j["pmr"]),
-        exec_jitter=float(j["exec_jitter"]),
-        demands=j["demands"],
-        sampler_of_worker=sampler_of_worker,
-    )
-
-
-def heterogeneous_machines(
-    m: int, seed: int, mem_range: Sequence[int], cpu_range: Sequence[int],
-    gpu_range: Sequence[int], bw_choices_GBps: Sequence[float],
-) -> List[Dict[str, float]]:
-    """The paper's Sec. VI-B random cluster: per machine a NIC speed drawn
-    from ``bw_choices`` and integer memory, cores and GPUs drawn uniformly
-    from their ranges (inclusive)."""
-    rng = np.random.default_rng(seed)
-    out = []
-    for _ in range(m):
-        bw = float(rng.choice(np.asarray(bw_choices_GBps)))
-        out.append({
-            "mem": float(rng.integers(int(mem_range[0]), int(mem_range[1]) + 1)),
-            "cpu": float(rng.integers(cpu_range[0], cpu_range[1] + 1)),
-            "gpu": float(rng.integers(gpu_range[0], gpu_range[1] + 1)),
-            "bw_GBps": bw,
-        })
-    return out
-
-
-def bandwidths(cfg: Dict[str, Any]) -> np.ndarray:
-    """[M] NIC speed of each machine, GB/s (ingress = egress)."""
-    return np.array([m["bw_GBps"] for m in cfg["cluster"]["machines"]], dtype=np.float64)
-
-
 @dataclass
 class Deployment:
     """One configuration, as the benchmark and as the program see it."""
 
     name: str
-    cfg: Dict[str, Any]
     job: Job
-    bw: np.ndarray  # [M] GB/s
+    bw: np.ndarray  # [M] GB/s, each machine's NIC speed
     workload: Any  # repro.core.Workload
     cluster: Any  # repro.core.ClusterSpec
+    n_iters: int  # iterations of one whole job draw
+    demand: np.ndarray  # [J, R] each task's resource demand
+    capacity: np.ndarray  # [M, R] each machine's capacity, same resources
+    movable: List[int]  # tasks a placement search may move
+    # reference_mean(y, bw, draws, dtype) -> mean makespan of placement y
+    # over draws, summed in draw order, computed in dtype
+    reference_mean: Callable[[np.ndarray, np.ndarray, Draws, type], float]
+    # reference_schedule(y, bw, vol, ex, dtype) -> the reference's schedule
+    # (makespan, starts [J, N]) of one draw
+    reference_schedule: Callable[[np.ndarray, np.ndarray, np.ndarray, np.ndarray, type], Any]
+    # without(m, bw) -> (the program's cluster, NIC speeds) once machine m
+    # has left, from NIC speeds bw of the whole cluster
+    without: Callable[[int, np.ndarray], Tuple[Any, np.ndarray]]
 
 
-def program_cluster(machines: Sequence[Dict[str, float]], bw: Optional[np.ndarray] = None) -> Any:
-    from repro.core import ClusterSpec, Machine
-
-    bw = bandwidths({"cluster": {"machines": machines}}) if bw is None else bw
-    return ClusterSpec(machines=[
-        Machine(
-            name=f"m{i}",
-            resources={r: float(m[r]) for r in ("mem", "cpu", "gpu")},
-            bw_in=float(b), bw_out=float(b),
-        )
-        for i, (m, b) in enumerate(zip(machines, bw))
-    ])
+def load_module(path: Path, name: str) -> ModuleType:
+    """Import the Python file ``path`` as module ``name``."""
+    spec = importlib.util.spec_from_file_location(name, path)
+    if spec is None or spec.loader is None or not path.is_file():
+        raise FileNotFoundError(f"no module file {path}")
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
 
 
-def load_deployment(name: str, path: Path) -> Deployment:
-    """Build configuration ``name`` from its file ``path`` and check that
-    the program's job is the benchmark's: same tasks, edges, lags, mean
-    volumes and exec times."""
-    from repro.core import build_gnn_workload
-
+def load_deployment(name: str, path: Path, root: Path) -> Deployment:
+    """Build configuration ``name`` from its file ``path`` through its kind,
+    ``<root>/chipbench/deployments/<kind>.py``."""
     cfg = json.loads(path.read_text())
-    job = build_job(cfg)
-    j = cfg["job"]
-    vol_s = sampler_volume_gb(j)
-    wl = build_gnn_workload(
-        n_stores=j["n_stores"], n_workers=j["n_workers"],
-        samplers_per_worker=j["samplers_per_worker"], n_ps=j["n_ps"],
-        n_iters=j["n_iters"], store_to_sampler_gb=vol_s,
-        sampler_to_worker_gb=vol_s, grad_gb=j["grad_gb"],
-        store_exec_s=j["store_exec_s"], sampler_exec_s=j["sampler_exec_s"],
-        worker_exec_s=j["worker_exec_s"], ps_exec_s=j["ps_exec_s"],
-        pmr=j["pmr"], demands=j["demands"],
-    )
-    same = (
-        wl.J == job.J and wl.E == job.E
-        and np.array_equal(wl.edge_src, job.src)
-        and np.array_equal(wl.edge_dst, job.dst)
-        and np.array_equal(wl.edge_lag, job.lag)
-        and np.allclose(wl.traffic.mean_volume, job.mean_volume, rtol=1e-15, atol=0)
-        and np.allclose(wl.traffic.mean_exec, job.mean_exec, rtol=1e-15, atol=0)
-        and np.array_equal(wl.traffic.fluctuating, job.fluctuating)
-        and [t.kind for t in wl.tasks] == job.kinds
-        and wl.traffic.exec_jitter == job.exec_jitter
-    )
-    if not same:
-        raise RuntimeError(f"{name}: the program's workload is not the configured job")
-    machines = cfg["cluster"]["machines"]
-    return Deployment(
-        name=name, cfg=cfg, job=job, bw=bandwidths(cfg), workload=wl,
-        cluster=program_cluster(machines),
-    )
+    kind = cfg.get("kind", DEFAULT_KIND)
+    if not re.fullmatch(r"[A-Za-z0-9_]+", str(kind)):
+        raise ValueError(f"{name}: kind {kind!r} is not a module name")
+    mod = load_module(root / "chipbench" / "deployments" / f"{kind}.py", f"chipbench_kind_{kind}")
+    return mod.load(name, cfg)

@@ -65,7 +65,9 @@ def self_ns(ops):
     return own
 
 
-def read(ctx):
+def scope_share(ctx, scope):
+    """Self time of the device ops in phase ``scope`` over device busy
+    inside the engine spans (%), or None where nothing can be read."""
     from repro.core import engine_jax
 
     spans = ctx.trace.spans.get("engine", [])
@@ -84,8 +86,10 @@ def read(ctx):
         if k >= 0:
             names[k].add(name)
     phases = [call_phases(scopes, n) for n in names]
-    ns = 0
-    for (name, _, _), k, own in zip(ctx.trace.ops, call, self_ns(ctx.trace.ops)):
-        if k >= 0 and phases[k][name] == "rate_solve":
-            ns += own
+    ns = sum(own for (name, _, _), k, own in zip(ctx.trace.ops, call, self_ns(ctx.trace.ops))
+             if k >= 0 and phases[k][name] == scope)
     return 100.0 * ns / busy
+
+
+def read(ctx):
+    return scope_share(ctx, "rate_solve")

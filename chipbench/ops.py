@@ -15,9 +15,11 @@ Every input is drawn from the run's seed.  Three operations exist:
 Each ``Ops`` object builds its inputs (``__init__``), warms the engine
 shapes its operations use (``warm``), runs one operation (``run``, which
 returns a record), and afterwards compares a sample of the records with the
-plain reference (``check``).  Only public entry points of ``repro`` are
-called, through their module attributes, so that a test can break the timed
-path underneath.
+deployment's plain reference (``check``).  An operation reaches the
+deployment only through its ``Deployment``, so that every kind of
+deployment runs the same operations.  Only public entry points of
+``repro`` are called, through their module attributes, so that a test can
+break the timed path underneath.
 """
 from __future__ import annotations
 
@@ -26,8 +28,7 @@ from typing import Any, Callable, Dict, List, Tuple
 
 import numpy as np
 
-from . import reference
-from .deploy import Deployment, program_cluster
+from .deploy import Deployment
 
 OP_SEED_BOUND = 2**31 - 1  # op seeds stay inside 32 signed bits
 
@@ -82,8 +83,8 @@ class Ops:
 
     def ref_mean(self, y: np.ndarray, bw: np.ndarray, draws, control: bool) -> Tuple[float, float]:
         """(reference mean makespan in float64, the control's in float32)."""
-        ref = reference.mean_makespan(self.dep.job, y, bw, draws)
-        low = reference.mean_makespan(self.dep.job, y, bw, draws, np.float32) if control else float("nan")
+        ref = self.dep.reference_mean(y, bw, draws, np.float64)
+        low = self.dep.reference_mean(y, bw, draws, np.float32) if control else float("nan")
         return ref, low
 
     def warm_widths(self, widths, n_iters: int, cluster: Any = None, migrations=None) -> None:
@@ -112,8 +113,7 @@ class PlanOps(Ops):
 
         # one job draw for every seed: the plans' seeds differ, the job does
         # not, so the committed makespans of two runs compare
-        n = dep.cfg["job"]["n_iters"]
-        self.vol, self.ex = dep.job.realize(traffic["realization_seed"], n)
+        self.vol, self.ex = dep.job.realize(traffic["realization_seed"], dep.n_iters)
         self.realization = core.Realization(volumes=self.vol, exec_times=self.ex)
 
     def warm(self) -> None:
@@ -146,11 +146,9 @@ class PlanOps(Ops):
             draws = job.draws(d["win_seed"], self.tr["sim_iters"], 1)
             ref, low = self.ref_mean(d["y"], bw, draws, control)
             search = max(search, rel_err(low if control else d["search_cost"], ref))
-            full = reference.simulate(job.src, job.dst, job.lag, job.J, d["y"], bw, self.vol, self.ex)
+            full = self.dep.reference_schedule(d["y"], bw, self.vol, self.ex, np.float64)
             if control:
-                full32 = reference.simulate(
-                    job.src, job.dst, job.lag, job.J, d["y"], bw, self.vol, self.ex, np.float32
-                )
+                full32 = self.dep.reference_schedule(d["y"], bw, self.vol, self.ex, np.float32)
                 got_m, got_s = full32.makespan, full32.starts
             else:
                 got_m, got_s = d["makespan"], d["starts"]
@@ -199,16 +197,11 @@ class ScoreOps(Ops):
         super().__init__(dep, traffic, seed)
         import repro.core as core
 
-        job = dep.job
-        res = ("cpu", "gpu", "mem")
-        demand = np.array([[job.demands[k].get(r, 0.0) for r in res] for k in job.kinds])
-        cap = np.array([[m[r] for r in res] for m in dep.cfg["cluster"]["machines"]])
-        movable = [j for j, k in enumerate(job.kinds) if k != "store"]
         pool: List[np.ndarray] = []
         for _ in range(traffic["pool_ifs"]):
             y = core.ifs_placement(dep.workload, dep.cluster, seed=self.op_seed()).y
             pool.append(y)
-            pool += single_moves(y, movable, demand, cap, self.rng, traffic["pool_moves"])
+            pool += single_moves(y, dep.movable, dep.demand, dep.capacity, self.rng, traffic["pool_moves"])
         self.pool = pool
         if len(pool) < traffic["candidates"]:
             raise RuntimeError(f"candidate pool holds {len(pool)} placements")
@@ -279,7 +272,8 @@ class ReplanOps(Ops):
         # the clean (width 1) and migration-loaded (width 2) scoring shapes,
         # on the full cluster and on one that has lost a machine
         n = self.tr["sim_iters"]
-        for cl in (self.dep.cluster, program_cluster(self.dep.cfg["cluster"]["machines"][:-1])):
+        last = len(self.dep.bw) - 1
+        for cl in (self.dep.cluster, self.dep.without(last, self.dep.bw)[0]):
             self.warm_widths([1], n, cl)
             for g in self.tr["warm_migration_flows"]:
                 flows = [core.MigrationFlow(src=0, dst=1, gb=0.1)] * g
@@ -317,7 +311,7 @@ class ReplanOps(Ops):
         for i in self.sample(len(records), self.tr["check_ops"]):
             d = records[i].data
             draws = self.dep.job.draws(d["seed"], self.tr["sim_iters"], 1)
-            bws = [d["bw"], np.delete(d["bw"], d["leave"])]
+            bws = [d["bw"], self.dep.without(d["leave"], d["bw"])[1]]
             for k in range(2):
                 ref, low = self.ref_mean(d["ys"][k], bws[k], draws, control)
                 worst = max(worst, rel_err(low if control else d["makespans"][k], ref))

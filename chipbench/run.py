@@ -4,7 +4,8 @@
 
 from the root of a checkout holding ``BENCHMARK.json``.  The cell names a
 configuration (``chipbench/configs/<name>.json``) and a traffic mix
-(``chipbench/traffic/<name>.json``).  One run:
+(``chipbench/traffic/<name>.json``); the configuration's kind
+(``chipbench/deployments/<kind>.py``) builds the deployment.  One run:
 
 1. selects the program's jitted engine (``REPRO_ENGINE_BACKEND=jax``) before
    ``repro`` is imported, and keeps JAX's persistent compilation cache in
@@ -17,8 +18,8 @@ configuration (``chipbench/configs/<name>.json``) and a traffic mix
    before then completes.  With ``--trace 1`` it runs the traffic's
    ``trace_ops`` operations under the profiler instead, with the
    benchmark's spans around the program's layers;
-5. compares a sample of what the window produced with the plain reference
-   (``reference.py``) and prints each compared number beside its limit;
+5. compares a sample of what the window produced with the deployment's
+   plain reference and prints each compared number beside its limit;
 6. prints one JSON line: ``correct``, ``attempted``, ``failed``, ``metrics``
    (the cell's end-to-end metrics, or with ``--trace 1`` its per-layer
    metrics), ``device``, with ``--trace 1`` ``breakdown``, and last
@@ -27,7 +28,6 @@ configuration (``chipbench/configs/<name>.json``) and a traffic mix
 from __future__ import annotations
 
 import argparse
-import importlib.util
 import json
 import math
 import os
@@ -183,13 +183,10 @@ def applies(metric: Dict[str, Any], cell: str) -> bool:
 def load_reader(metric: str, root: Path) -> Callable[[Any], Optional[float]]:
     """The reader of per-layer metric ``a.b``: ``chipbench/metrics/a.py``'s
     ``read(ctx)``."""
+    from chipbench.deploy import load_module
+
     path = root / "chipbench" / "metrics" / f"{metric.split('.')[0]}.py"
-    spec = importlib.util.spec_from_file_location(f"chipbench_metric_{path.stem}", path)
-    if spec is None or spec.loader is None or not path.is_file():
-        raise FileNotFoundError(f"no reader for metric {metric!r}: {path}")
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    return load_module(path, f"chipbench_metric_{path.stem}").read
 
 
 @dataclass
@@ -252,7 +249,7 @@ def run_cell(
     wl = {w["name"]: w for w in bench["workloads"]}[cell]
     cfg_entry = {c["name"]: c for c in bench["configs"]}[wl["config"]]
     device = device_info(int(wl["chips"]), require_tpu)
-    dep = deploy.load_deployment(wl["config"], root / cfg_entry["file"])
+    dep = deploy.load_deployment(wl["config"], root / cfg_entry["file"], root)
     traffic = json.loads((root / "chipbench" / "traffic" / f"{wl['traffic']}.json").read_text())
     ops = ops_mod.make_ops(dep, traffic, seed)
     ops.warm()

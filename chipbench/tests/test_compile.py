@@ -79,7 +79,7 @@ def compile_for_chip(one_chip, jax_ready, monkeypatch):
 @pytest.mark.parametrize("cell", BENCH["workloads"], ids=lambda w: w["name"])
 def test_cell_shapes_compile_for_v5e(cell, compile_for_chip):
     cfg = {c["name"]: c for c in BENCH["configs"]}[cell["config"]]
-    dep = deploy.load_deployment(cell["config"], ROOT / cfg["file"])
+    dep = deploy.load_deployment(cell["config"], ROOT / cfg["file"], ROOT)
     traffic = json.loads((ROOT / "chipbench" / "traffic" / f"{cell['traffic']}.json").read_text())
     if traffic["op"] == "replan":  # the fewest and the most migration columns
         traffic["warm_migration_flows"] = [min(traffic["warm_migration_flows"]),
