@@ -99,9 +99,9 @@ def testbed_job(profile: Any, n_iters: int) -> Any:
     )
 
 
-def testbed_inputs() -> Tuple[Any, Any, List[Any], List[Any], List[Tuple[str, dict]]]:
-    """Phase (a)'s batch: the products testbed job, WIDTH IFS placements x
-    realizations, and its cases — the five policies unshaped, then oes
+def testbed_inputs(width: int = WIDTH) -> Tuple[Any, Any, List[Any], List[Any], List[Tuple[str, dict]]]:
+    """Phase (a)'s batch: the products testbed job, ``width`` IFS placements
+    x realizations, and its cases — the five policies unshaped, then oes
     deadline-shaped under a drift trace with migration flows."""
     from repro.core import MigrationFlow, ifs_placement, simulate, testbed_cluster
     from repro.core.engine_jax import JAX_POLICIES
@@ -110,8 +110,8 @@ def testbed_inputs() -> Tuple[Any, Any, List[Any], List[Any], List[Tuple[str, di
 
     wl = testbed_job(OGBN_PRODUCTS, n_iters=15)
     cluster = testbed_cluster()
-    placements = [ifs_placement(wl, cluster, seed=s) for s in range(WIDTH)]
-    reals = [wl.realize(seed=s) for s in range(WIDTH)]
+    placements = [ifs_placement(wl, cluster, seed=s) for s in range(width)]
+    reals = [wl.realize(seed=s) for s in range(width)]
     horizon = simulate(wl, cluster, placements[0], reals[0]).makespan * 1.5
     trace = drift_trace(
         cluster, horizon_s=horizon, n_segments=6, seed=0,
@@ -129,7 +129,7 @@ def testbed_inputs() -> Tuple[Any, Any, List[Any], List[Any], List[Tuple[str, di
         None,
         [MigrationFlow(src=1, dst=0, gb=0.8, task=wl.J - 1, deadline=3.0)],
     ]
-    migrations = [patterns[b % 3] for b in range(WIDTH)]
+    migrations = [patterns[b % 3] for b in range(width)]
     cases: List[Tuple[str, dict]] = [(pol, {}) for pol in JAX_POLICIES]
     cases.append(("oes", dict(shaping="deadline", trace=trace, migrations=migrations)))
     return wl, cluster, placements, reals, cases

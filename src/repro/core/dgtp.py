@@ -85,9 +85,9 @@ def plan(
     default (``DEFAULT_N_CHAINS``): the jax engine evaluates each
     lock-step batch in one jitted call, so its default runs MORE chains
     at the same budget (wider batches, more basins — re-derived from the
-    measured sweep in benchmarks/bench_engine.py).  The final committed
-    schedule always runs on the reference numpy engine: it is ONE
-    simulation, and its recorded ``flow_log`` feeds the audit artifacts."""
+    measured sweep in benchmarks/bench_engine.py).  The committed schedule
+    runs on the same engine, recorded: its task events and ``flow_log``
+    feed the chain certificate."""
     with span("repro.plan", seq=next_seq(), budget=budget):
         realization = realization or workload.realize(seed=seed)
         backend = resolve_backend(backend)
@@ -112,13 +112,10 @@ def plan(
                 placement = etp.placement
             else:
                 placement = ifs_placement(workload, cluster, seed=seed)
-        # committed schedule: pinned to numpy even when REPRO_ENGINE_BACKEND=jax —
-        # the certificate's chain construction follows the recorded flow_log,
-        # which the jax engine does not produce (ONE simulation; never hot).
         with span("repro.plan.commit.simulate"):
             schedule = simulate(
                 workload, cluster, placement, realization, policy=policy,
-                record=True, backend="numpy",
+                record=True, backend=backend,
             )
         with span("repro.plan.commit.audit"):
             cert = chain_lower_bound(workload, cluster, placement, realization, schedule)

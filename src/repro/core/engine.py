@@ -54,7 +54,8 @@ at ``engine_jax.PARITY_RTOL`` (certified by tests/test_jax_engine.py) and
 multiplies planner placement-evaluations/sec on planner-scale workloads
 (measured in benchmarks/bench_engine.py and the ROADMAP perf log).  The
 jax backend supports the five built-in policies (custom ``RatePolicy``
-callables raise a clear error) and does not record ``flow_log``.
+callables raise a clear error) and records ``flow_log`` like this engine
+when ``record=True``.
 """
 from __future__ import annotations
 
@@ -573,13 +574,11 @@ class ScheduleResult:
     """One simulated schedule.
 
     ``flow_log`` is a list of ``(edge, iter, start, end)`` tuples when the
-    run was recorded (``record=True`` on the numpy backend) and ``None``
-    when it was NOT recorded — ``record=False``, or any jax-backend run:
-    the jitted program never materialises per-flow spans (use the
-    ``aggregates`` counters from ``engine_jax.simulate_batch_jax(...,
-    utilization=True)`` instead, or re-run with ``backend="numpy"``).
-    ``None`` (not ``[]``) so "unrecorded" can never be confused with "a
-    recorded schedule that happened to have no remote flows".
+    run was recorded (``record=True``, on either backend: the same flow
+    instances, zero-volume ones left out) and ``None`` when it was NOT
+    recorded (``record=False``).  ``None`` (not ``[]``) so "unrecorded"
+    can never be confused with "a recorded schedule that happened to have
+    no remote flows".
 
     ``n_events`` diverges between backends BY DESIGN: the numpy engine
     counts discrete events (task completions, flow deliveries, trace
@@ -633,9 +632,9 @@ def simulate(
     ``backend`` selects the simulation engine (``resolve_backend``:
     explicit > ``REPRO_ENGINE_BACKEND`` > numpy).  ``"jax"`` runs the job
     as a width-1 ``engine_jax.simulate_batch_jax`` call — same event
-    semantics at ``PARITY_RTOL``, no ``flow_log`` (see the module
-    docstring's backend section); scalar simulation is numpy's home turf,
-    the knob exists so a jax-selected stack never silently mixes engines.
+    semantics and recorded ``flow_log`` at ``PARITY_RTOL`` (see the module
+    docstring's backend section), so a jax-selected stack never silently
+    mixes engines.
 
     ``migrations`` (a sequence of ``MigrationFlow``) injects one-shot state
     moves released at t=0 that compete for NIC bandwidth with the training

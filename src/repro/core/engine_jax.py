@@ -28,11 +28,14 @@ Precision/parity contract: the backend runs in float64 (x64 is enabled at
 import, an explicit and tested choice — see tests/test_jax_engine.py) and
 agrees with the numpy engine on makespans and task-start schedules at
 ``PARITY_RTOL`` (XLA may fuse multiply-adds, so bit-equality is not
-promised the way numpy batch-vs-scalar is).  Known divergences, by design:
+promised the way numpy batch-vs-scalar is).  Known divergence, by design:
 ``n_events`` counts jitted lock-step iterations (zero-duration cascades
-settle in one iteration instead of several) and ``flow_log`` is ``None``
-— never recorded (``record=True`` still yields exact ``task_events``).
-In place of per-flow spans the program can carry cheap IN-PROGRAM
+settle in one iteration instead of several).  ``record=True`` compiles
+task and flow span arrays (``[B, J, N]`` and ``[B, E + G, N]``) into the
+loop and yields ``task_events`` and the numpy engine's ``flow_log``: the
+same flow instances, zero-volume ones left out, migration flows as
+instance 1; a runner with ``record=False`` carries neither.
+Without recording, the program can carry cheap IN-PROGRAM
 aggregate accumulators (``utilization=True``): per-machine NIC
 utilization integrals (GB delivered into/out of each machine — the
 integral of the rate solve over every advance step), per-machine
@@ -170,6 +173,9 @@ class _State(NamedTuple):
     migleft: object  # [B, J]
     start_rec: object  # [B, J, N] (nan when not recorded)
     end_rec: object  # [B, J, N]
+    fstart_rec: object  # [B, EG, N] flow instance starts (None unless record:
+    #   no placeholder, so a runner that records nothing carries nothing)
+    fend_rec: object  # [B, EG, N] flow instance ends
     util_in: object  # [B, M] GB delivered into each machine ((1,1) off)
     util_out: object  # [B, M] GB sent out of each machine ((1,1) off)
     busy: object  # [B, M] seconds with >=1 running task ((1,1) off)
@@ -511,6 +517,9 @@ def _build_runner(
                 rem_o = jnp.maximum(rem_o - sum_src(sm), 0.0)
             return r, rounds
 
+        # the iteration axis of the recorded task and flow spans
+        iters = jnp.arange(N)[None, None, :] if record else None
+
         # ---- settle: fixpoint of same-instant completions/arms/starts ----
         def settle_round(s: _State) -> _State:
             t = s.t
@@ -534,6 +543,12 @@ def _build_runner(
                     migleft = migleft - dec.astype(jnp.int32)
             remaining = jnp.where(fin, 0.0, s.remaining)
             active = s.active & ~fin
+            fstart_rec, fend_rec = s.fstart_rec, s.fend_rec
+            if record:  # the finishing instance is delivered + 1
+                fend_rec = jnp.where(
+                    fin[:, :, None] & (iters == s.delivered[:, :, None]),
+                    t[:, None, None], fend_rec,
+                )
 
             nxt = delivered + 1
             src_done = done[:, src_t_eg]
@@ -560,6 +575,11 @@ def _build_runner(
                 else s.release
             )
             active = active | arm
+            if record:  # zero-volume instances are delivered, never logged
+                fstart_rec = jnp.where(
+                    arm[:, :, None] & (iters == (nxt - 1)[:, :, None]),
+                    t[:, None, None], fstart_rec,
+                )
 
             ncand = done + 1
             need = ncand[:, dst_t_e] - lag_e[None, :]
@@ -587,8 +607,7 @@ def _build_runner(
             start_rec, end_rec = s.start_rec, s.end_rec
             if record:
                 sel = can[:, :, None] & (
-                    jnp.arange(N)[None, None, :]
-                    == jnp.clip(ncand - 1, 0, N - 1)[:, :, None]
+                    iters == jnp.clip(ncand - 1, 0, N - 1)[:, :, None]
                 )
                 start_rec = jnp.where(sel, t[:, None, None], start_rec)
                 end_rec = jnp.where(sel, end_new[:, :, None], end_rec)
@@ -620,6 +639,8 @@ def _build_runner(
                     migleft=migleft,
                     start_rec=start_rec,
                     end_rec=end_rec,
+                    fstart_rec=fstart_rec,
+                    fend_rec=fend_rec,
                 ),
                 changed,
             )
@@ -694,8 +715,7 @@ def _build_runner(
             agg = {}
             if collect:
                 # in-program observability integrals: GB moved this step
-                # per flow, folded onto the NIC / class axes (the jax
-                # engine's stand-in for the numpy flow_log)
+                # per flow, folded onto the NIC / class axes
                 dvol = r * dtb[:, None]
                 agg["util_in"] = s.util_in + sum_dst(dvol)
                 agg["util_out"] = s.util_out + sum_src(dvol)
@@ -751,6 +771,12 @@ def _build_runner(
             migleft=migleft0,
             start_rec=jnp.full(rec_shape, jnp.nan),
             end_rec=jnp.full(rec_shape, jnp.nan),
+            # migration columns armed at init start at 0 as instance 1
+            fstart_rec=(
+                jnp.where(active0[:, :, None] & (iters == 0), 0.0, jnp.nan)
+                if record else None
+            ),
+            fend_rec=jnp.full((B, EG, N), jnp.nan) if record else None,
             util_in=jnp.zeros(agg_shape),
             util_out=jnp.zeros(agg_shape),
             busy=jnp.zeros(agg_shape),
@@ -776,6 +802,7 @@ def _build_runner(
         return (
             s.t, s.nev, s.stuck, alive, s.start_rec, s.end_rec,
             s.util_in, s.util_out, s.busy, s.clsgb, s.fill,
+            s.fstart_rec, s.fend_rec,
         )
 
     return jax.jit(run)
@@ -1019,14 +1046,14 @@ def simulate_batch_jax(
 
     Same signature and event semantics; returns one ``ScheduleResult`` per
     instance agreeing with the numpy engine at ``PARITY_RTOL`` (float64).
-    ``flow_log`` is always ``None`` (never recorded) and ``n_events``
-    counts jitted lock-step iterations — see the module docstring for the
-    exact contract.  ``utilization=True`` compiles the in-program
-    aggregate accumulators into the loop (its own jit cache entry) and
-    fills ``ScheduleResult.aggregates`` with per-machine NIC utilization
-    integrals (``nic_in_gb``/``nic_out_gb``), busy-time integrals
-    (``busy_s``) and per-class delivered bytes (``class_gb``) — the
-    observability substitute for the flow log this backend cannot afford.
+    ``record=True`` fills ``task_events`` and ``flow_log`` (``None``
+    otherwise); ``n_events`` counts jitted lock-step iterations — see the
+    module docstring for the exact contract.  ``utilization=True`` compiles
+    the in-program aggregate accumulators into the loop (its own jit cache
+    entry) and fills ``ScheduleResult.aggregates`` with per-machine NIC
+    utilization integrals (``nic_in_gb``/``nic_out_gb``), busy-time
+    integrals (``busy_s``) and per-class delivered bytes (``class_gb``):
+    the totals a flow log gives, without recording one.
     """
     if not HAVE_JAX:  # pragma: no cover
         raise RuntimeError(
@@ -1062,13 +1089,15 @@ def simulate_batch_jax(
             runner = _runner_for(key, build, args)
         eng.set_metadata(runner=runner.rid)
         with span("repro.engine.dispatch"):
-            *outs, fill = runner.fn(*args)
+            *outs, fill, fstart_rec, fend_rec = runner.fn(*args)
         with span("repro.engine.fetch"):
             t, nev, stuck, alive = (np.asarray(a)[:B] for a in outs[:4])
             start_rec, end_rec, util_in, util_out, busy, clsgb = outs[4:]
             if record:
                 start_rec = np.asarray(start_rec)[:B]
                 end_rec = np.asarray(end_rec)[:B]
+                fstart_rec = np.asarray(fstart_rec)[:B]
+                fend_rec = np.asarray(fend_rec)[:B]
             if utilization:
                 util_in = np.asarray(util_in)[:B]
                 util_out = np.asarray(util_out)[:B]
@@ -1079,24 +1108,33 @@ def simulate_batch_jax(
         if alive.any():  # pragma: no cover
             raise RuntimeError("event limit exceeded — dependency deadlock?")
         with span("repro.engine.unpack"):
-            J = workload.J
             agg_levels = build["agg_levels"]
             out: List[ScheduleResult] = []
+            n_flows = 0
             for b in range(B):
                 events: List[TaskEvent] = []
+                flow_log = None
                 if record:
-                    order = sorted(
-                        (
-                            (start_rec[b, j, n], j, n)
-                            for j in range(J)
-                            for n in range(N)
-                            if not np.isnan(start_rec[b, j, n])
-                        ),
-                    )
+                    # task instances by (start, task, iteration); flow
+                    # instances by (end, edge, iteration): the numpy
+                    # engine's delivery order, ties by edge
+                    j, n = np.nonzero(~np.isnan(start_rec[b]))
+                    st = start_rec[b, j, n]
+                    o = np.lexsort((n, j, st))
                     events = [
-                        TaskEvent(j, n + 1, float(st), float(end_rec[b, j, n]))
-                        for st, j, n in order
+                        TaskEvent(*ev) for ev in zip(
+                            j[o].tolist(), (n[o] + 1).tolist(),
+                            st[o].tolist(), end_rec[b, j[o], n[o]].tolist(),
+                        )
                     ]
+                    e, n = np.nonzero(~np.isnan(fend_rec[b]))
+                    en = fend_rec[b, e, n]
+                    o = np.lexsort((n, e, en))
+                    flow_log = list(zip(
+                        e[o].tolist(), (n[o] + 1).tolist(),
+                        fstart_rec[b, e[o], n[o]].tolist(), en[o].tolist(),
+                    ))
+                    n_flows += len(flow_log)
                 agg = None
                 if utilization:
                     agg = {
@@ -1112,7 +1150,7 @@ def simulate_batch_jax(
                     ScheduleResult(
                         makespan=float(t[b]),
                         task_events=events,
-                        flow_log=None,
+                        flow_log=flow_log,
                         n_events=int(nev[b]),
                         policy=policy.name,
                         aggregates=agg,
@@ -1127,4 +1165,6 @@ def simulate_batch_jax(
         reg.counter("engine.jax.padded_rows").inc(Bp - B)
         reg.counter("engine.jax.lockstep_iters").inc(int(nev.max()))
         reg.counter("engine.jax.fill_rounds").inc(int(fill))
+        if record:
+            reg.counter("engine.jax.recorded_flows").inc(n_flows)
     return out

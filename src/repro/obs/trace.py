@@ -1,7 +1,8 @@
 """Structured schedule traces: task/flow spans + NIC utilization timelines.
 
-``ScheduleTrace.from_result`` lifts a *recorded* numpy-engine schedule
-(``simulate(..., record=True)``) into an analysable object:
+``ScheduleTrace.from_result`` lifts a *recorded* schedule
+(``simulate(..., record=True)``, on either engine) into an analysable
+object:
 
   * one ``TaskSpan`` per task instance (machine, kind, realized vs
     nominal duration);
@@ -14,11 +15,11 @@
     the bytes delivered through that NIC *exactly* — the conservation
     invariant the test suite pins, and the same quantity the jax
     backend's in-program accumulators report (``ScheduleResult.
-    aggregates``) for runs that cannot afford a flow log.
+    aggregates``) for runs that record none.
 
-The jax backend never records a flow log (``flow_log is None``), so
-``from_result`` raises a descriptive error for those results instead of
-silently producing an empty trace.
+An unrecorded run (``record=False``) has no flow log (``flow_log is
+None``), so ``from_result`` raises a descriptive error for those results
+instead of silently producing an empty trace.
 """
 from __future__ import annotations
 
@@ -115,17 +116,16 @@ class ScheduleTrace:
     ) -> "ScheduleTrace":
         """Build a trace from ``simulate(..., record=True)`` output.
 
-        Raises ``ValueError`` for results without a flow log (any jax-
-        backend run, or ``record=False``).
+        Raises ``ValueError`` for results without a flow log
+        (``record=False``).
         """
         if res.flow_log is None:
             raise ValueError(
-                "ScheduleResult has no flow_log (flow_log is None): the jax "
-                "backend never records per-flow spans and record=False "
-                "records nothing — re-run with backend='numpy' and "
-                "record=True, or use the jax engine's aggregate counters "
-                "(simulate_batch_jax(..., utilization=True) -> "
-                "ScheduleResult.aggregates)."
+                "ScheduleResult has no flow_log (flow_log is None): "
+                "record=False records nothing — re-run with record=True "
+                "(backend='numpy' or backend='jax'), or use the jax "
+                "engine's aggregate counters (simulate_batch_jax(..., "
+                "utilization=True) -> ScheduleResult.aggregates)."
             )
         y = placement.y
         names = workload.task_names()

@@ -30,9 +30,11 @@ Covered here:
 
 ``n_events`` is never compared with numpy's: the jax engine counts lock-step
 iterations (zero-duration cascades settle inside one), a documented
-divergence.  ``flow_log`` is ``None`` on the jax backend (never
-recorded, distinct from numpy's recorded-but-empty ``[]``);
-``task_events`` are exact and are what the start-matrix checks consume.
+divergence.  A recorded run's ``flow_log`` holds the numpy engine's flow
+instances, compared sorted at the same tolerance (the order of flows that
+end at one instant is the engine's own); an unrecorded one is ``None`` on
+both backends.  ``record=False`` runners, which every search and scoring
+call runs, are pinned by the text of their lowered program.
 """
 import contextlib
 import json
@@ -71,13 +73,28 @@ SHAPINGS = (None, "strict", "deadline")
 
 
 def _assert_parity(wl, ref, got, n_iters):
-    """Makespan + full task-start schedule agreement at the pinned tol."""
+    """Makespan, full task-start schedule and flow log agreement at the
+    pinned tol."""
     assert np.isclose(ref.makespan, got.makespan,
                       rtol=PARITY_RTOL, atol=PARITY_ATOL)
     sm_r = ref.task_start_matrix(wl.J, n_iters)
     sm_g = got.task_start_matrix(wl.J, n_iters)
     assert np.allclose(sm_r, sm_g, rtol=PARITY_RTOL, atol=PARITY_ATOL,
                        equal_nan=True)
+    _assert_flow_log_parity(ref, got)
+
+
+def _assert_flow_log_parity(ref, got):
+    """The same flow instances, (edge, iteration) for (edge, iteration),
+    with starts and ends at the pinned tol; unrecorded on both or neither."""
+    if ref.flow_log is None:
+        assert got.flow_log is None
+        return
+    want, have = sorted(ref.flow_log), sorted(got.flow_log)
+    assert [f[:2] for f in have] == [f[:2] for f in want]
+    assert np.allclose(np.array([f[2:] for f in have]).reshape(-1, 2),
+                       np.array([f[2:] for f in want]).reshape(-1, 2),
+                       rtol=PARITY_RTOL, atol=PARITY_ATOL)
 
 
 # ---------------------------------------------------------------------------
@@ -190,7 +207,13 @@ def test_golden_suite_jax(golden, name, regime):
             starts = res.task_start_matrix(wl.J, realization.n_iters)
             assert np.allclose(starts, np.array(pinned["task_start"]),
                                rtol=PARITY_RTOL, atol=PARITY_ATOL)
-            assert res.flow_log is None  # documented jax-backend divergence
+            ref = simulate(
+                wl, cluster, placement, realization, policy=policy,
+                record=True, trace=trace, migrations=flows, shaping=shaping,
+                backend="numpy",
+            )
+            assert res.flow_log
+            _assert_flow_log_parity(ref, res)
 
 
 # ---------------------------------------------------------------------------
@@ -323,23 +346,30 @@ def test_plan_n_chains_defaults(routing_case):
     out = plan(wl, cluster, realization=r, budget=8, sim_iters=3,
                n_chains=2, backend="jax")
     assert out.schedule.makespan > 0
-    assert out.schedule.flow_log  # committed schedule stays on numpy
+    assert out.schedule.flow_log  # the jax commit records its flows
 
 
-def test_plan_env_jax_keeps_numpy_commit(routing_case, monkeypatch):
-    """Regression: with REPRO_ENGINE_BACKEND=jax set globally, plan()'s
-    COMMITTED schedule must still run on numpy — the certificate's chain
-    construction follows the recorded flow_log, which the jax engine never
-    produces (an env-routed commit used to yield an empty flow_log and a
-    degenerate ~0 chain lower bound)."""
+def test_plan_env_jax_commits_on_jax(routing_case, monkeypatch):
+    """With REPRO_ENGINE_BACKEND=jax, plan() commits on the jax engine,
+    which records the flow log the certificate's chain construction walks
+    (a commit without it would give a degenerate ~0 chain lower bound):
+    the certificate holds and its bound is the numpy commit's."""
     wl, cluster, p, r = routing_case
     monkeypatch.setenv("REPRO_ENGINE_BACKEND", "jax")
-    out = plan(wl, cluster, realization=r, budget=8, sim_iters=3, n_chains=2)
+    with _registry_on() as reg:
+        out = plan(wl, cluster, realization=r, budget=8, sim_iters=3,
+                   n_chains=2)
+        recorded = reg.snapshot()["engine.jax.recorded_flows"]["value"]
     assert out.schedule.flow_log
+    assert recorded == len(out.schedule.flow_log)  # the commit ran on jax
+    assert out.certificate.holds
     assert out.certificate.lower_bound > 0.1
     ref = plan(wl, cluster, realization=r, budget=8, sim_iters=3, n_chains=2,
-               backend="jax")
-    assert out.certificate.lower_bound == ref.certificate.lower_bound
+               backend="numpy")
+    assert np.array_equal(out.placement.y, ref.placement.y)
+    assert np.isclose(out.certificate.lower_bound, ref.certificate.lower_bound,
+                      rtol=PARITY_RTOL, atol=PARITY_ATOL)
+    _assert_parity(wl, ref.schedule, out.schedule, r.n_iters)
 
 
 # ---------------------------------------------------------------------------
@@ -573,10 +603,123 @@ def test_fill_rounds_count_every_class_level(shaping, levels):
     assert counters["engine.jax.fill_rounds"] == levels * iters
 
 
+def test_recorded_flows_count_the_flow_log_rows(matrix_case):
+    """``engine.jax.recorded_flows``: the flow log rows a recorded call
+    unpacks, over its instances (migration flows included); a call that
+    records nothing counts none."""
+    wl, cluster, placements, reals, dyn, migs = matrix_case
+    res, counters = _run_counted(wl, cluster, placements, reals, trace=dyn,
+                                 migrations=migs)
+    assert any(f[0] >= wl.E for f in res[0].flow_log)
+    assert counters["engine.jax.recorded_flows"] == sum(
+        len(r.flow_log) for r in res) > 0
+    with _registry_on() as reg:
+        simulate_batch_jax(wl, cluster, placements, reals)
+        assert "engine.jax.recorded_flows" not in reg.snapshot()
+
+
+# ---------------------------------------------------------------------------
+# runners that record nothing are pinned by their lowered program
+# ---------------------------------------------------------------------------
+# Every ETP search call and every scoring call runs ``record=False``: the
+# text of those runners as jax lowers them is pinned by its sha256, so a
+# change to what ``record=True`` runs cannot leak into them.  Regenerate
+# (ONLY for an intended change to those programs, with the cause named):
+#   PYTHONPATH=src:. python tests/test_jax_engine.py --regen-hlo
+RUNNER_HLO_PATH = GOLDEN_PATH.parent / "jax_runner_hlo.json"
+
+
+def _hlo_cases():
+    """{case id: (workload, cluster, placements, realizations, policy,
+    kwargs)}: the testbed job at the plan cell's search shapes (15
+    iterations) under every policy and the deadline/trace/migration
+    regime, and the papers100M job at the score cell's (width 16, 4
+    iterations).  A runner's program depends on the shapes, the topology
+    and the static regime, not on the placements' or draws' values."""
+    import chip_smoke
+    from repro.core.profiles import OGBN_PAPERS100M
+
+    wl, cluster, placements, reals, cases = chip_smoke.testbed_inputs(width=16)
+    out = {}
+    for policy, kw in cases:
+        regime = "-deadline-trace-migrations" if kw else ""
+        out[f"testbed-w16-i15-{policy}{regime}"] = (
+            wl, cluster, placements, reals, policy, kw)
+    out["testbed-w1-i15-oes"] = (wl, cluster, placements[:1], reals[:1], "oes", {})
+    big = build_workload_from_profile(
+        OGBN_PAPERS100M, n_stores=16, n_workers=20, samplers_per_worker=4,
+        n_ps=1, n_iters=4,
+    )
+    big_cluster = heterogeneous_cluster(16, seed=1)
+    y = Placement(np.arange(big.J, dtype=np.int64) % big_cluster.M)
+    out["papers100m-w16-i4-oes"] = (
+        big, big_cluster, [y] * 16, [big.realize(seed=s) for s in range(16)],
+        "oes", {})
+    return out
+
+
+class _Lowered(Exception):
+    pass
+
+
+def _runner_hlo_sha256(case, monkeypatch):
+    """sha256 of the lowered text of the ``record=False`` runner that
+    ``simulate_batch_jax`` builds for ``case`` (built, lowered, never run)."""
+    import hashlib
+
+    wl, cluster, placements, reals, policy, kw = case
+    texts = []
+    build = engine_jax._build_runner
+
+    def build_and_lower(**bkw):
+        fn = build(**bkw)
+
+        def lower(*args):
+            texts.append(fn.lower(*args).as_text())
+            raise _Lowered
+
+        return lower
+
+    with monkeypatch.context() as mp:
+        mp.setattr(engine_jax, "_build_runner", build_and_lower)
+        mp.setattr(engine_jax, "_RUNNERS", {})
+        with pytest.raises(_Lowered):
+            simulate_batch_jax(wl, cluster, placements, reals, policy=policy,
+                               record=False, **kw)
+    (text,) = texts
+    return hashlib.sha256(text.encode()).hexdigest()
+
+
+@pytest.fixture(scope="module")
+def hlo_cases():
+    return _hlo_cases()
+
+
+HLO_CASE_IDS = (
+    [f"testbed-w16-i15-{p}" for p in POLICIES]
+    + ["testbed-w16-i15-oes-deadline-trace-migrations", "testbed-w1-i15-oes",
+       "papers100m-w16-i4-oes"]
+)
+
+
+@pytest.mark.parametrize("case_id", HLO_CASE_IDS)
+def test_unrecorded_runner_program_pinned(hlo_cases, case_id, monkeypatch):
+    """A runner that records nothing lowers to the pinned program text."""
+    pinned = json.loads(RUNNER_HLO_PATH.read_text())
+    assert sorted(pinned) == sorted(HLO_CASE_IDS)
+    assert _runner_hlo_sha256(hlo_cases[case_id], monkeypatch) == pinned[case_id]
+
+
 if __name__ == "__main__":
     import sys
 
-    if "--regen" in sys.argv:
+    if "--regen-hlo" in sys.argv:
+        with pytest.MonkeyPatch.context() as mp:
+            pinned = {cid: _runner_hlo_sha256(case, mp)
+                      for cid, case in _hlo_cases().items()}
+        RUNNER_HLO_PATH.write_text(json.dumps(pinned, indent=1) + "\n")
+        print(f"wrote {RUNNER_HLO_PATH}: {sorted(pinned)}")
+    elif "--regen" in sys.argv:
         named = [a for a in sys.argv[sys.argv.index("--regen") + 1:]
                  if not a.startswith("-")]
         pinned = regen_jax_oes(named)

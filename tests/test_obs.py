@@ -198,6 +198,53 @@ def test_jax_aggregates_match_numpy_trace():
     assert res_plain.aggregates is None
 
 
+FANIN = [c for c in CASES if c[0].startswith("fanin-")]
+
+
+@pytest.mark.parametrize("case", FANIN, ids=[c[0] for c in FANIN])
+def test_trace_from_jax_recorded_result_matches_numpy(case):
+    """A schedule recorded on the jax engine lifts into the numpy
+    schedule's trace: the same task and flow spans, times at the engines'
+    parity tolerance, and the same NIC and class aggregates."""
+    pytest.importorskip("jax")
+    from repro.core.engine_jax import PARITY_ATOL, PARITY_RTOL
+
+    _, wl, cluster, placement, r, tr, flows, shaping = case
+
+    def lifted(backend):
+        res = simulate(
+            wl, cluster, placement, r, policy="oes", trace=tr,
+            migrations=flows, shaping=shaping, record=True, backend=backend,
+        )
+        return ScheduleTrace.from_result(
+            res, wl, cluster, placement, r, trace=tr, migrations=flows,
+            shaping=shaping,
+        )
+
+    ref, got = lifted("numpy"), lifted("jax")
+    close = dict(rtol=PARITY_RTOL, atol=PARITY_ATOL)
+    assert got.makespan == pytest.approx(ref.makespan, rel=PARITY_RTOL)
+
+    def spans(tr):
+        return (sorted(tr.tasks, key=lambda s: (s.task, s.iter)),
+                sorted(tr.flows, key=lambda f: (f.edge, f.iter)))
+
+    (t_ref, f_ref), (t_got, f_got) = spans(ref), spans(got)
+    assert f_ref
+    assert [(s.task, s.iter, s.machine, s.name) for s in t_got] == [
+        (s.task, s.iter, s.machine, s.name) for s in t_ref]
+    assert [(f.edge, f.iter, f.src, f.dst, f.gb, f.cls, f.name) for f in f_got] == [
+        (f.edge, f.iter, f.src, f.dst, f.gb, f.cls, f.name) for f in f_ref]
+    np.testing.assert_allclose([[s.start, s.end] for s in t_got + f_got],
+                               [[s.start, s.end] for s in t_ref + f_ref], **close)
+    np.testing.assert_allclose([f.ideal_s for f in f_got],
+                               [f.ideal_s for f in f_ref], **close)
+    agg_ref, agg_got = ref.aggregates(), got.aggregates()
+    for k in ("nic_in_gb", "nic_out_gb", "busy_s"):
+        np.testing.assert_allclose(agg_got[k], agg_ref[k], **close)
+    assert agg_got["class_gb"] == pytest.approx(agg_ref["class_gb"], rel=PARITY_RTOL)
+
+
 # ---------------------------------------------------------------------------
 # metrics registry
 # ---------------------------------------------------------------------------

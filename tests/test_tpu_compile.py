@@ -116,6 +116,20 @@ def test_testbed_runner_compiles_for_v5e(testbed, compile_for_chip, case):
     _check(compile_for_chip)
 
 
+def test_testbed_commit_runner_compiles_for_v5e(compile_for_chip):
+    """plan()'s commit on the testbed: one placement over 60 iterations,
+    width 1, with task and flow spans recorded."""
+    from repro.core import ifs_placement, simulate, testbed_cluster
+    from repro.core.profiles import OGBN_PRODUCTS
+
+    wl = chip_smoke.testbed_job(OGBN_PRODUCTS, n_iters=60)
+    cluster = testbed_cluster()
+    with pytest.raises(_Compiled):
+        simulate(wl, cluster, ifs_placement(wl, cluster, seed=0),
+                 wl.realize(seed=0), policy="oes", record=True, backend="jax")
+    _check(compile_for_chip)
+
+
 def test_papers100m_wide_runner_compiles_for_v5e(compile_for_chip):
     """§VI-B papers100M job (16 machines, J=117, E=1400), oes, width 1024.
     Its program holds no per-element gather (slice sizes all 1): on a
