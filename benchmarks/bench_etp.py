@@ -1,18 +1,12 @@
-"""ETP planning-loop throughput: batched lock-step simulation vs scalar.
+"""ETP planning-loop benches on the CPU.
 
-The paper's placement search (Alg. 3) is bounded by how many candidate
-simulations fit in the time budget, so this bench reports
-placement-evaluations/sec for:
+  * ``simulate_batch`` at widths 1-32 (events/sec per width); on the numpy
+    backend a batch is the reference ``simulate`` run instance by
+    instance, so the rows track the reference's per-event cost, and
+  * end-to-end ``plan()`` wall time.
 
-  * the scalar path (``use_batch=False``: one event-driven simulation per
-    MCMC proposal per draw, the seed behaviour), and
-  * the batched fast path (``use_batch=True``: all chains' proposals x all
-    Monte-Carlo draws advanced in one ``simulate_batch`` lock-step).
-
-Both paths are bit-identical in results (tests/test_batch_engine.py), so
-the ratio is pure planning-loop speedup.  Also measured: the fused
-``expected_makespan`` (all draws in one batch) and end-to-end ``plan()``
-wall time.
+CPU timings, not speed figures: ``chipbench/`` measures the jitted engine
+on the chip.
 
 Run: ``PYTHONPATH=src python -m benchmarks.bench_etp``
 """
@@ -20,9 +14,9 @@ from __future__ import annotations
 
 from .common import Timer, emit  # noqa: F401 (inserts src/ into sys.path)
 
-from repro.core import expected_makespan, plan, simulate_batch
+from repro.core import plan, simulate_batch
 from repro.core.cluster import testbed_cluster
-from repro.core.placement import etp_multichain, ifs_placement
+from repro.core.placement import ifs_placement
 from repro.core.profiles import OGBN_PRODUCTS, build_workload_from_profile
 
 
@@ -30,55 +24,6 @@ def paper_job(n_iters: int = 12):
     return build_workload_from_profile(
         OGBN_PRODUCTS, n_stores=4, n_workers=6, samplers_per_worker=2,
         n_ps=1, n_iters=n_iters,
-    )
-
-
-def multichain_throughput(n_chains: int = 16, budget: int = 480, sim_iters: int = 12):
-    """Headline number: evaluations/sec of etp_multichain, batched vs
-    scalar, at a FIXED search budget (identical seeds -> identical search
-    trajectory and evaluation count on both paths).  sim_draws stays 1 so
-    the scalar path is the seed's pure per-proposal simulate() loop — with
-    draws > 1 even the "scalar" path would use the fused draw batch."""
-    wl = paper_job(sim_iters)
-    cluster = testbed_cluster()
-    kw = dict(n_chains=n_chains, budget=budget, sim_iters=sim_iters, seed=0)
-    with Timer() as t_seq:
-        seq = etp_multichain(wl, cluster, use_batch=False, **kw)
-    with Timer() as t_bat:
-        bat = etp_multichain(wl, cluster, use_batch=True, **kw)
-    assert seq.best_makespan == bat.best_makespan, "batch/scalar diverged!"
-    assert seq.cost_trace == bat.cost_trace, "batch/scalar diverged!"
-    # both paths perform the same number of simulations; count them from the
-    # winning chain's bookkeeping scaled by chains (uniform budgets)
-    evals = seq.evaluations + seq.cache_hits
-    eps_seq = n_chains * evals / t_seq.dt
-    eps_bat = n_chains * evals / t_bat.dt
-    speedup = t_seq.dt / t_bat.dt
-    emit(
-        "etp_multichain_scalar", t_seq.us,
-        f"chains={n_chains} budget={budget} evals_per_s={eps_seq:.1f}",
-    )
-    emit(
-        "etp_multichain_batched", t_bat.us,
-        f"chains={n_chains} budget={budget} evals_per_s={eps_bat:.1f} "
-        f"speedup={speedup:.2f}x (identical results certified)",
-    )
-    return speedup
-
-
-def fused_expected_makespan(n_draws: int = 8):
-    wl = paper_job()
-    cluster = testbed_cluster()
-    p = ifs_placement(wl, cluster, seed=0)
-    with Timer() as t_loop:
-        a = expected_makespan(wl, cluster, p, n_draws=n_draws, batch=False)
-    with Timer() as t_fused:
-        b = expected_makespan(wl, cluster, p, n_draws=n_draws, batch=True)
-    assert a == b
-    emit(
-        "expected_makespan_fused", t_fused.us,
-        f"draws={n_draws} loop={t_loop.dt*1e3:.0f}ms fused={t_fused.dt*1e3:.0f}ms "
-        f"speedup={t_loop.dt/t_fused.dt:.2f}x",
     )
 
 
@@ -120,10 +65,7 @@ def plan_wall_time(budget: int = 400):
 
 def main():
     batch_width_scaling()
-    fused_expected_makespan()
-    speedup = multichain_throughput()
     plan_wall_time()
-    emit("etp_batch_speedup_headline", 0.0, f"{speedup:.2f}x at fixed budget")
 
 
 if __name__ == "__main__":

@@ -8,7 +8,8 @@ benches).  Prints ``name,us_per_call,derived`` CSV rows.
   fig7/9          papers100M 16-machine sim: batch-size + PMR sweeps
   competitive     Theorem-1 empirical certificate table
   etp_*           ETP ablation (paper-faithful vs enhanced) + 5-min claim
-  etp             batched-vs-scalar planning-loop throughput (bench_etp)
+  etp             simulate_batch width rows + end-to-end plan() wall time
+                  (bench_etp)
   cache           feature-cache sweeps + cache-aware ETP (bench_cache)
   dynamics        drift-trace re-planning: static vs replan vs oracle,
                   warm-vs-cold evaluations-to-quality (bench_dynamics;
@@ -16,9 +17,6 @@ benches).  Prints ``name,us_per_call,derived`` CSV rows.
   arrivals        multi-tenant arrival streams: service vs EDF/SJF/RR
                   deadline compliance, rejection isolation, tenant-blame
                   conservation, incremental-merge churn (bench_arrivals)
-  engine_*        event-engine throughput: numpy vs jitted jax backend
-                  across batch width and workload scale (bench_engine;
-                  every row asserts makespan parity first)
   obs_*           observability overhead: metrics registry off/on on the
                   engine rows (asserts the <3% off-path pin) + the full
                   record->trace->blame->perfetto pipeline cost (bench_obs)
@@ -38,7 +36,6 @@ from . import (
     bench_arrivals,
     bench_cache,
     bench_dynamics,
-    bench_engine,
     bench_etp,
     bench_figures,
     bench_kernels,
@@ -81,12 +78,12 @@ def main() -> None:
         "--only", default=None,
         choices=[
             None, "figures", "algorithms", "kernels", "roofline", "etp",
-            "cache", "dynamics", "engine", "obs", "arrivals",
+            "cache", "dynamics", "obs", "arrivals",
         ],
     )
     ap.add_argument(
         "--smoke", action="store_true",
-        help="CI-sized budgets (honoured by the dynamics, engine and obs "
+        help="CI-sized budgets (honoured by the dynamics, arrivals and obs "
         "benches)",
     )
     ap.add_argument(
@@ -106,9 +103,6 @@ def main() -> None:
     if args.only in (None, "etp"):
         set_group("etp")
         bench_etp.main()
-    if args.only in (None, "engine"):
-        set_group("engine")
-        bench_engine.main(smoke=args.smoke)
     if args.only in (None, "cache"):
         set_group("cache")
         bench_cache.main()

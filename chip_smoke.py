@@ -217,9 +217,7 @@ def phase_papers100m() -> None:
     got, first, run = _twice(lambda: simulate_batch_jax(
         wl, cluster, placements, reals, policy="oes", record=True,
     ))
-    # the numpy lock-step batch engine is bit-identical to 16 scalar
-    # simulate() calls (tests/test_batch_engine.py) at a seventh of the
-    # host time
+    # the numpy reference on 16 spread instances
     check = np.linspace(0, WIDE - 1, 16).astype(int)
     refs = simulate_batch(
         wl, cluster, [placements[b] for b in check], [reals[b] for b in check],

@@ -111,8 +111,8 @@ def cache_cost_fns(
 
     All candidates share one set of Monte-Carlo draws (apples-to-apples
     across the whole search) and ``batch_cost`` runs every pending
-    (candidate x draw) pair in ONE ``simulate_batch`` call — the PR-1 fast
-    path is preserved, only the volumes fed to it change per candidate.
+    (candidate x draw) pair in ONE ``simulate_batch`` call; only the
+    volumes fed to it change per candidate.
     ``machine_models`` (machine -> HitModel) overrides the shared model on
     specific machines (heterogeneous budgets).  ``backend`` selects the
     simulation engine (``engine.resolve_backend``) — the rewritten volumes

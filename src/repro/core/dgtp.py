@@ -23,15 +23,14 @@ from .placement import (
 from .workload import Realization, Workload
 from ..obs.spans import next_seq, span
 
-# Default ETP chain count per engine backend, re-derived from the measured
-# chain sweep (ROADMAP perf log; pinned by tests/test_jax_engine.py).
-# numpy: 8 — the PR-1 sweet spot.  jax: 16 — on the planner-scale sweep
-# (budget 512, 6 machines) the jitted engine plans in ~1.0s at 16 chains
-# vs ~0.8s at 8 and vs numpy-8's ~6.2s, with best-makespan flat from 8 up
-# — doubling the basin count is nearly free on the jax backend.  Beyond 16
-# the per-chain memoisation caches stop overlapping their own history
-# (more cache misses = more simulations), costing wall with no measured
-# quality gain.
+# Default ETP chain count per engine backend (pinned by
+# tests/test_jax_engine.py).  numpy: 8.  jax: 16 — in a planner-scale CPU
+# sweep (budget 512, 6 machines) the jitted engine planned in about 1.0 s
+# at 16 chains and 0.8 s at 8, with best-makespan flat from 8 up, so
+# doubling the basin count was nearly free there.  Beyond 16 the per-chain
+# memoisation caches stop overlapping their own history (more cache misses
+# = more simulations), costing wall with no measured quality gain.  Neither
+# value has been re-derived on a TPU.
 DEFAULT_N_CHAINS = {"numpy": 8, "jax": 16}
 
 
@@ -68,14 +67,10 @@ def plan(
     orders — DGTP's placement is then at least as good as every baseline's
     under its own scheduler, for any budget (the single-chain
     paper-faithful search is etp_search).  The chains advance in lock-step
-    with their candidate placements evaluated in one batched simulation
-    (engine.simulate_batch), so planning wall time shrinks with the chain
-    count at identical search semantics — which is why the default is 8
-    chains: at a fixed transition ``budget`` the batch width quadruples vs
-    the old 2-chain default (wall time drops accordingly,
-    benchmarks/bench_etp.py) at comparable placement quality (8 shallower
+    with each step's candidate placements evaluated in one
+    ``engine.simulate_batch`` call.  At a fixed transition ``budget`` more
     chains explore more basins but walk each less; the two effects roughly
-    cancel on the testbed jobs).  Raising ``n_chains`` with ``budget``
+    cancel on the testbed jobs.  Raising ``n_chains`` with ``budget``
     scaled proportionally is never worse — chains are seed-nested in that
     regime (tests/test_cache.py).
 
@@ -84,10 +79,9 @@ def plan(
     ``REPRO_ENGINE_BACKEND`` > numpy) and with it the ``n_chains``
     default (``DEFAULT_N_CHAINS``): the jax engine evaluates each
     lock-step batch in one jitted call, so its default runs MORE chains
-    at the same budget (wider batches, more basins — re-derived from the
-    measured sweep in benchmarks/bench_engine.py).  The committed schedule
-    runs on the same engine, recorded: its task events and ``flow_log``
-    feed the chain certificate."""
+    at the same budget (wider batches, more basins).  The committed
+    schedule runs on the same engine, recorded: its task events and
+    ``flow_log`` feed the chain certificate."""
     with span("repro.plan", seq=next_seq(), budget=budget):
         realization = realization or workload.realize(seed=seed)
         backend = resolve_backend(backend)

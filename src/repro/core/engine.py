@@ -35,24 +35,20 @@ ignore classes entirely and match the pre-class engine bit-for-bit.
 Implementation notes: because constraint (11) serialises a logical edge's
 instances, *at most one instance per edge is ever in flight* — the active
 flow set is a boolean mask over the E logical edges, and all per-event work
-is vectorised numpy over that mask.  This is the engine used by ETP's inner
-loop, so constant factors matter: ``simulate_batch`` advances many
-independent (placement, realization) instances in lock-step so the
-per-event numpy overhead is amortised across the whole batch
-(benchmarks/bench_etp.py measures the resulting planning-loop throughput).
+is vectorised numpy over that mask.
 
 Backends: ``simulate`` / ``simulate_batch`` / ``expected_makespan`` (and
 every consumer that threads the knob — placement search, re-planning, the
 cache-aware and multi-job objectives) accept ``backend="numpy" | "jax"``,
 defaulting to the ``REPRO_ENGINE_BACKEND`` environment variable and then
 to ``"numpy"``.  The numpy engine in this module is the REFERENCE
-implementation: exact event-by-event float64, bit-identical batch vs
-scalar, full ``flow_log``.  ``backend="jax"`` routes batched calls through
-``engine_jax.simulate_batch_jax`` — one jitted ``lax.while_loop`` array
-program per (width-bucket, topology, policy) that agrees with this engine
-at ``engine_jax.PARITY_RTOL`` (certified by tests/test_jax_engine.py) and
-multiplies planner placement-evaluations/sec on planner-scale workloads
-(measured in benchmarks/bench_engine.py and the ROADMAP perf log).  The
+implementation: event-exact float64 with a full ``flow_log``.
+``simulate_batch`` on numpy is that reference run instance by instance.
+``backend="jax"`` routes calls through ``engine_jax.simulate_batch_jax``,
+the lock-step batch engine: one jitted ``lax.while_loop`` array program
+per (width-bucket, topology, policy) that advances every instance of a
+batch together and agrees with the reference at
+``engine_jax.PARITY_RTOL`` (certified by tests/test_jax_engine.py).  The
 jax backend supports the five built-in policies (custom ``RatePolicy``
 callables raise a clear error) and records ``flow_log`` like this engine
 when ``record=True``.
@@ -343,12 +339,12 @@ def _class_shaped_rates(
     mode, cls, deadline, remaining, src_m, dst_m, bw_in, bw_out, now,
     minlength, base_call,
 ):
-    """The per-class leftover-capacity loop shared by the scalar
-    ``ShapedPolicy.rates`` and the pooled batch path: classes ascending,
-    each rated by ``base_call(mask, rem_in, rem_out)`` against what the
-    classes above left over, single class short-circuiting to a full-
-    capacity pass-through (``mask=None``).  One implementation keeps the
-    scalar and pooled engines bit-identical by construction."""
+    """The per-class leftover-capacity loop shared by
+    ``ShapedPolicy.rates`` and the slotted transcription
+    (``oes_slotted.py``): classes ascending, each rated by
+    ``base_call(mask, rem_in, rem_out)`` against what the classes above
+    left over, single class short-circuiting to a full-capacity
+    pass-through (``mask=None``)."""
     eff = _effective_classes(
         mode, cls, deadline, remaining, src_m, dst_m, bw_in, bw_out, now
     )
@@ -448,29 +444,6 @@ def resolve_policy(policy: "RatePolicy | str", shaping: Optional[str] = None) ->
         else:
             policy = ShapedPolicy(policy, shaping)
     return policy
-
-
-def _policy_traits(
-    policy: RatePolicy, inert_deadlines: bool = False
-) -> Tuple[RatePolicy, bool, bool, bool]:
-    """(inner, needs_group, rates_cacheable, topo_cacheable) for the batch
-    engine's rate caching.  Shaped ``strict`` keeps the base policy's
-    cacheability (rates are still a pure function of the active-flow
-    topology + classes, and classes are fixed per column); ``deadline``
-    reads ``remaining`` and the clock, so it must be recomputed every
-    event, exactly like mrtf/omcoflow — UNLESS the run carries no finite
-    deadline at all (``inert_deadlines``), where deadline mode is
-    certified bit-identical to strict and keeps strict's caches."""
-    if isinstance(policy, ShapedPolicy):
-        inner = policy.base
-        static_shaping = policy.mode == "strict" or inert_deadlines
-    else:
-        inner = policy
-        static_shaping = True
-    needs_group = inner.name not in ("oes", "oes_strict", "fifo", "mrtf")
-    rates_cacheable = static_shaping and inner.name in ("oes", "oes_strict", "fifo")
-    topo_cacheable = static_shaping and inner.name in ("oes", "oes_strict")
-    return inner, needs_group, rates_cacheable, topo_cacheable
 
 
 def _check_edge_classes(
@@ -688,6 +661,28 @@ def simulate(
             migrations=[migrations] if migrations is not None else None,
             shaping=shaping, edge_classes=edge_classes,
         )[0]
+    return _simulate_reference(
+        workload, cluster, placement, realization, policy, record, max_events,
+        trace, migrations, shaping, edge_classes,
+    )
+
+
+def _simulate_reference(
+    workload: Workload,
+    cluster: ClusterSpec,
+    placement: Placement,
+    realization: Realization,
+    policy: RatePolicy | str,
+    record: bool,
+    max_events: int,
+    trace: Optional["BandwidthTrace"],
+    migrations: Optional[Sequence[MigrationFlow]],
+    shaping: Optional[str],
+    edge_classes: Optional["ArrayLike"],
+) -> ScheduleResult:
+    """The numpy event loop behind ``simulate``: the reference engine,
+    event-exact in float64.  ``simulate_batch`` on numpy runs it once per
+    instance."""
     policy = resolve_policy(policy, shaping)
     shaped = isinstance(policy, ShapedPolicy)
     N = realization.n_iters
@@ -951,258 +946,6 @@ def simulate(
     )
 
 
-# ---------------------------------------------------------------------------
-# Batched engine: many independent (placement, realization) instances advance
-# in lock-step.  Each lock-step iteration moves every unfinished instance to
-# its own next event, so the per-event numpy overhead (rate computation, time
-# stepping) is paid once per iteration instead of once per instance — the
-# planning loop's evaluations/sec scale with the batch width.
-#
-# Exactness contract: for every instance the batched path performs the exact
-# same floating-point operations as ``simulate`` run on that instance alone,
-# so makespans / schedules are bit-identical (certified by
-# tests/test_batch_engine.py).  The rate policies decompose because instances
-# never share NICs: machine ids are offset per instance (``b*M + m``) and all
-# built-in policies act component-locally on the resulting disjoint union —
-# except OES progressive filling, whose global water level is replaced by a
-# per-instance level advanced in lock-step (same per-instance increment
-# sequence as the scalar loop).
-# ---------------------------------------------------------------------------
-def _batch_rates_factory(
-    policy: RatePolicy,
-    B: int,
-    cluster: ClusterSpec,
-    group_stride: int,
-    bw_in_mat: np.ndarray,
-    bw_out_mat: np.ndarray,
-    dynamic: bool = False,
-) -> Callable[..., np.ndarray]:
-    """Return ``f(inst, src, dst, remaining, release, group) -> rates`` for
-    flows pooled from up to ``B`` instances (``inst`` sorted ascending).
-    ``src`` / ``dst`` / ``group`` are instance-local; the pool is compacted
-    to the distinct instances actually present (rate caching usually leaves
-    only one or two dirty), and a single-instance pool short-circuits to the
-    scalar policy — exact by definition.  ``bw_in_mat`` / ``bw_out_mat``
-    are the [B, M] per-instance NIC capacities, owned by the driver: with
-    ``dynamic`` (a bandwidth trace) each instance's row tracks its own
-    current segment and pooled calls gather the present instances' rows
-    fresh; without one every row is identical, so pooled calls keep the
-    old zero-copy slice of the flat tiling.  Callers must run inside an
-    ``np.errstate(divide/invalid ignored)`` context.
-
-    A ``ShapedPolicy`` pools too: the per-class capacity passes run over
-    the pooled disjoint union (instances never share NICs, so per-class
-    leftovers stay instance-local by construction) with each class's flows
-    rated by the BASE policy's pooled rule — per-instance heterogeneous
-    class sets (e.g. only some instances carrying migration flows) are
-    exact because a class absent from an instance contributes nothing to
-    that instance's capacity arithmetic.  ``rates_fn`` then takes three
-    extra per-flow arrays (``cls`` / ``dl`` / ``now``), ``None`` when the
-    policy is unshaped."""
-    M = cluster.M
-    shaped = isinstance(policy, ShapedPolicy)
-    inner = policy.base if shaped else policy
-    if not dynamic:
-        bw_in_flat = bw_in_mat.reshape(-1)
-        bw_out_flat = bw_out_mat.reshape(-1)
-
-    if inner.name == "oes_strict":
-
-        def strict_pool(nb, src, dst, remaining, release, group, bw_in_p, bw_out_p, inst):
-            d_out = np.bincount(src, minlength=nb * M)
-            d_in = np.bincount(dst, minlength=nb * M)
-            return np.minimum(
-                bw_in_p[dst] / d_in[dst],
-                bw_out_p[src] / d_out[src],
-            )
-
-        pool_rates = strict_pool
-
-    elif inner.name in ("fifo", "mrtf"):
-        # Sequential waterfill: a stable sort keeps each instance's internal
-        # priority order, and capacity updates are per-NIC, so interleaving
-        # instances changes nothing within any one of them.
-        def waterfill_pool(nb, src, dst, remaining, release, group, bw_in_p, bw_out_p, inst):
-            rem_in = bw_in_p.astype(np.float64)  # int bw would truncate rem -= give
-            rem_out = bw_out_p.astype(np.float64)
-            r = np.zeros(len(src))
-            order = inner.order(src, dst, remaining, release, rem_in, rem_out)
-            for i in order:
-                give = min(rem_in[dst[i]], rem_out[src[i]])
-                if give > EPS:
-                    r[i] = give
-                    rem_in[dst[i]] -= give
-                    rem_out[src[i]] -= give
-            return r
-
-        pool_rates = waterfill_pool
-
-    elif inner.name == "omcoflow":
-        # The scalar rule's only global quantity, min(bw_in.max(), bw_out.max()),
-        # is computed per instance from its own current bandwidth row, so
-        # pooling stays exact under both static and dynamic clusters.
-        rounds = inner.rounds
-
-        def omcoflow_pool(nb, src, dst, remaining, release, group, bw_in_p, bw_out_p, inst):
-            # zero-bandwidth guards mirror the scalar rule bit-for-bit
-            pred = np.maximum(remaining, EPS) / np.maximum(
-                np.minimum(bw_in_p[dst], bw_out_p[src]), EPS
-            )
-            w = 1.0 / pred
-            gsum = np.zeros(group.max() + 1)
-            np.add.at(gsum, group, w)
-            w = w / np.maximum(gsum[group], EPS)
-            ref_b = np.minimum(
-                bw_in_p.reshape(nb, M).max(axis=1),
-                bw_out_p.reshape(nb, M).max(axis=1),
-            )
-            r = w * ref_b[inst]
-            for _ in range(rounds):
-                load_out = np.bincount(src, weights=r, minlength=nb * M)
-                load_in = np.bincount(dst, weights=r, minlength=nb * M)
-                s_out = bw_out_p / np.maximum(load_out, EPS)
-                s_in = bw_in_p / np.maximum(load_in, EPS)
-                r = r * np.minimum(1.0, np.minimum(s_out[src], s_in[dst]))
-            return r
-
-        pool_rates = omcoflow_pool
-
-    elif inner.name == "oes":
-        # Per-instance progressive filling in lock-step: every round, each
-        # still-filling instance raises its unfrozen flows by ITS OWN
-        # bottleneck increment (not a global water level), reproducing the
-        # scalar per-instance increment sequence exactly.  Ingress NICs
-        # occupy [0, nb*M) and egress NICs [nb*M, 2*nb*M) of one fused
-        # capacity array so each round costs one bincount / one where.
-        def oes_pool(nb, src, dst, remaining, release, group, bw_in_p, bw_out_p, inst):
-            # An instance whose flows all froze (or vanished) gets an
-            # all-zero NIC count, hence an infinite increment, hence is
-            # killed by the isfinite check — no separate emptiness pass
-            # needed (bitwise equivalent: no increment is applied either way).
-            n = len(src)
-            src2 = src + nb * M
-            idx2 = np.concatenate((dst, src2))
-            r = np.zeros(n)
-            rem2 = np.concatenate((bw_in_p, bw_out_p))
-            unfrozen = np.ones(n, dtype=bool)
-            live = np.ones(nb, dtype=bool)  # instance still filling
-            flows = unfrozen.copy()
-            for _ in range(2 * (M + M)):
-                cnt2 = np.bincount(
-                    idx2[np.concatenate((flows, flows))], minlength=2 * nb * M
-                )
-                inc2 = np.where(cnt2 > 0, rem2 / np.maximum(cnt2, 1), np.inf)
-                inc_side = inc2.reshape(2 * nb, M).min(axis=1)
-                inc_b = np.minimum(inc_side[:nb], inc_side[nb:])
-                live &= np.isfinite(inc_b)
-                flows &= live[inst]
-                if not flows.any():
-                    break
-                r[flows] += inc_b[inst[flows]]
-                inc_f = np.where(live, inc_b, 0.0)
-                rem2.reshape(2, nb, M)[...] -= inc_f[None, :, None] * cnt2.reshape(2, nb, M)
-                sat2 = (rem2 <= EPS) & (cnt2 > 0)
-                newly = flows & (sat2[dst] | sat2[src2])
-                live &= np.bincount(inst[newly], minlength=nb) > 0
-                unfrozen &= ~newly
-                flows &= unfrozen & live[inst]
-                if not flows.any():
-                    break
-            return r
-
-        pool_rates = oes_pool
-
-    else:
-        pool_rates = None  # unknown/custom policy: per-segment scalar calls
-
-    if shaped and pool_rates is not None:
-        base_pool = pool_rates
-
-        def shaped_pool(nb, src, dst, remaining, release, group,
-                        bw_in_p, bw_out_p, inst, cls, dl, now):
-            # the shared per-class loop over the pooled disjoint union:
-            # the leftover arithmetic is per-NIC, hence per-instance, so
-            # processing a class an instance doesn't have leaves that
-            # instance's arrays bit-identical (x - 0 == x and the >=0
-            # clamp is idempotent).
-            def base_call(m, rem_in, rem_out):
-                if m is None:
-                    return base_pool(
-                        nb, src, dst, remaining, release, group,
-                        rem_in, rem_out, inst,
-                    )
-                return base_pool(
-                    nb, src[m], dst[m], remaining[m], release[m],
-                    group[m] if group is not None else None,
-                    rem_in, rem_out, inst[m],
-                )
-
-            return _class_shaped_rates(
-                policy.mode, cls, dl, remaining, src, dst,
-                bw_in_p, bw_out_p, now, nb * M, base_call,
-            )
-
-    def rates_fn(inst, src_l, dst_l, remaining, release, group,
-                 cls=None, dl=None, now=None):
-        # boundaries of the (sorted) instance segments in the pool
-        cut = np.empty(len(inst), dtype=bool)
-        cut[0] = True
-        np.not_equal(inst[1:], inst[:-1], out=cut[1:])
-        nb = int(cut.sum())
-        if nb == 1:
-            b = int(inst[0])
-            if shaped:
-                return policy.rates(
-                    src_l, dst_l, remaining, release, group,
-                    bw_in_mat[b], bw_out_mat[b], cls=cls, deadline=dl, now=now,
-                )
-            return policy.rates(
-                src_l, dst_l, remaining, release, group,
-                bw_in_mat[b], bw_out_mat[b],
-            )
-        present = inst[cut]  # distinct instance ids, ascending
-        if pool_rates is None:
-            r = np.empty(len(inst))
-            starts = np.nonzero(cut)[0].tolist() + [len(inst)]
-            for lo, hi in zip(starts[:-1], starts[1:]):
-                b = int(inst[lo])
-                if shaped:
-                    r[lo:hi] = policy.rates(
-                        src_l[lo:hi], dst_l[lo:hi], remaining[lo:hi],
-                        release[lo:hi], group[lo:hi],
-                        bw_in_mat[b], bw_out_mat[b],
-                        cls=cls[lo:hi], deadline=dl[lo:hi], now=now[lo:hi],
-                    )
-                else:
-                    r[lo:hi] = policy.rates(
-                        src_l[lo:hi], dst_l[lo:hi], remaining[lo:hi],
-                        release[lo:hi], group[lo:hi],
-                        bw_in_mat[b], bw_out_mat[b],
-                    )
-            return r
-        if dynamic:
-            bw_in_p = bw_in_mat[present].ravel()
-            bw_out_p = bw_out_mat[present].ravel()
-        else:  # all rows identical: zero-copy view of the first nb tiles
-            bw_in_p = bw_in_flat[: nb * M]
-            bw_out_p = bw_out_flat[: nb * M]
-        dense = np.cumsum(cut) - 1  # 0..nb-1 per flow
-        src = src_l + dense * M
-        dst = dst_l + dense * M
-        if inner.name == "omcoflow":
-            group = group + dense * group_stride
-        if shaped:
-            return shaped_pool(
-                nb, src, dst, remaining, release, group,
-                bw_in_p, bw_out_p, dense, cls, dl, now,
-            )
-        return pool_rates(
-            nb, src, dst, remaining, release, group, bw_in_p, bw_out_p, dense
-        )
-
-    return rates_fn
-
-
 def simulate_batch(
     workload: Workload,
     cluster: ClusterSpec,
@@ -1217,35 +960,24 @@ def simulate_batch(
     edge_classes: Optional["ArrayLike"] = None,
     backend: Optional[str] = None,
 ) -> List[ScheduleResult]:
-    """Run ``B = len(placements)`` independent jobs to completion in
-    lock-step; instance ``b`` pairs ``placements[b]`` with
-    ``realizations[b]``.  Returns one ``ScheduleResult`` per instance,
-    bit-identical to ``simulate`` run on each instance alone.
+    """Run ``B = len(placements)`` independent jobs to completion; instance
+    ``b`` pairs ``placements[b]`` with ``realizations[b]``.  Returns one
+    ``ScheduleResult`` per instance.
+
+    On the numpy backend this is the reference engine run instance by
+    instance: each result IS ``simulate`` on that instance alone.  On the
+    jax backend (``resolve_backend``: explicit > ``REPRO_ENGINE_BACKEND`` >
+    numpy) the whole batch is one call of ``engine_jax.simulate_batch_jax``,
+    the lock-step engine, which agrees with the reference at
+    ``engine_jax.PARITY_RTOL``.
 
     ``migrations`` is per-instance: ``migrations[b]`` (None or a sequence
     of ``MigrationFlow``) is injected into instance ``b`` exactly as
-    ``simulate(..., migrations=...)`` would — instances with fewer flows
-    than the batch maximum carry inert padding columns that never
-    activate, so the lock-step stays bit-identical to per-instance scalar
-    runs with their own flow sets (the replan objective relies on this to
-    evaluate clean and migration-loaded variants in ONE batch).
-
-    All realizations must share ``n_iters`` (the batch is stacked into
-    ``[B, E, N]`` / ``[B, J, N]`` arrays); the cluster is shared.
-    ``trace`` (see ``simulate``) is shared too, but instances advance
-    through its segments on their own clocks — each instance carries its
-    own segment pointer and per-machine bandwidth row, so the lock-step
-    batch stays bit-identical to per-instance scalar runs on the same
-    trace (certified by tests/test_dynamics.py).
-
-    ``shaping`` / ``edge_classes`` follow ``simulate``: traffic classes are
-    per-instance heterogeneous through the per-instance migration flow sets
-    (``edge_classes`` is shared — one workload, one class per edge).
-
-    ``backend`` (``resolve_backend``: explicit > ``REPRO_ENGINE_BACKEND``
-    > numpy) routes the whole batch through the jitted jax engine — this
-    is the throughput path the knob exists for (see the module docstring's
-    backend section and benchmarks/bench_engine.py)."""
+    ``simulate(..., migrations=...)`` would, so one batch can evaluate
+    clean and migration-loaded variants side by side (the replan objective
+    relies on this).  All realizations must share ``n_iters``; the cluster,
+    ``trace``, ``shaping`` and ``edge_classes`` are shared by every
+    instance (see ``simulate``)."""
     if obs_metrics.REGISTRY.enabled:
         obs_metrics.REGISTRY.counter("engine.simulate_batch.calls").inc()
         obs_metrics.REGISTRY.counter("engine.simulate_batch.instances").inc(
@@ -1260,7 +992,6 @@ def simulate_batch(
             migrations=migrations, shaping=shaping, edge_classes=edge_classes,
         )
     policy = resolve_policy(policy, shaping)
-    shaped = isinstance(policy, ShapedPolicy)
     B = len(placements)
     if B == 0:
         return []
@@ -1269,416 +1000,14 @@ def simulate_batch(
     N = realizations[0].n_iters
     if any(r.n_iters != N for r in realizations):
         raise ValueError("all realizations in a batch must share n_iters")
-    J, E = workload.J, workload.E
-    src_t, dst_t, lag = workload.edge_src, workload.edge_dst, workload.edge_lag
-    vol = np.stack([r.volumes for r in realizations])  # [B, E, N]
-    ex = np.stack([r.exec_times for r in realizations])  # [B, J, N]
-    src_m = np.stack([p.y[src_t] for p in placements])  # [B, E]
-    dst_m = np.stack([p.y[dst_t] for p in placements])
-    local = src_m == dst_m
-    last_instance = N - lag  # [E]
-
-    # per-instance migration flows in pseudo-edge columns E..E+Gmax-1;
-    # instances with fewer flows leave inert (local=True) padding columns
     if migrations is not None and len(migrations) != B:
         raise ValueError("migrations must give one (possibly None) entry per instance")
-    mig_lists = [
-        check_migration_flows(m, cluster.M, J)
-        for m in (migrations if migrations is not None else [None] * B)
-    ]
-    Gmax = max((len(m) for m in mig_lists), default=0)
-    EG = E + Gmax
-    dst_t_grp, lag_grp = dst_t, lag
-    # traffic class + deadline per (instance, flow column); only gathered
-    # when the policy is shaped
-    flow_cls = np.zeros((B, EG), dtype=np.int64)
-    flow_dl = np.full((B, EG), np.inf)
-    ec = _check_edge_classes(edge_classes, E)
-    if ec is not None:
-        flow_cls[:, :E] = ec
-    if Gmax:
-        vol = np.concatenate([vol, np.zeros((B, Gmax, N))], axis=1)
-        src_m = np.concatenate([src_m, np.zeros((B, Gmax), dtype=np.int64)], axis=1)
-        dst_m = np.concatenate([dst_m, np.zeros((B, Gmax), dtype=np.int64)], axis=1)
-        local = np.concatenate([local, np.ones((B, Gmax), dtype=bool)], axis=1)
-        for b, ms in enumerate(mig_lists):
-            for g, f in enumerate(ms):
-                e = E + g
-                src_m[b, e] = f.src
-                dst_m[b, e] = f.dst
-                vol[b, e, 0] = f.gb
-                local[b, e] = (f.src == f.dst) or (f.gb <= EPS)
-                flow_cls[b, e] = f.cls
-                flow_dl[b, e] = f.deadline
-        dst_t_grp = np.concatenate([dst_t, J + np.arange(Gmax)])
-        lag_grp = np.concatenate([lag, np.zeros(Gmax, dtype=np.int64)])
-
-    # per-instance NIC capacity rows (and, with a trace, segment pointers)
-    if trace is None:
-        bw_in_mat = np.tile(np.asarray(cluster.bw_in, dtype=np.float64), (B, 1))
-        bw_out_mat = np.tile(np.asarray(cluster.bw_out, dtype=np.float64), (B, 1))
-        seg_times, n_segs, seg_b = None, 1, None
-        slow_l = None
-        t_break = np.full(B, np.inf)
-    else:
-        if trace.bw_in.shape[1] != cluster.M:
-            raise ValueError(
-                f"trace covers {trace.bw_in.shape[1]} machines but the "
-                f"cluster has {cluster.M} — rebuild the trace after "
-                "membership changes"
-            )
-        seg_times = np.asarray(trace.times, dtype=np.float64)
-        n_segs = len(seg_times)
-        bw_in_mat = np.tile(np.asarray(trace.bw_in[0], dtype=np.float64), (B, 1))
-        bw_out_mat = np.tile(np.asarray(trace.bw_out[0], dtype=np.float64), (B, 1))
-        seg_b = [0] * B
-        slow_l = [np.asarray(trace.slow[0], dtype=np.float64).tolist() for _ in range(B)]
-        t_break = np.full(B, seg_times[1] if n_segs > 1 else np.inf)
-        y_l = [p.y.tolist() for p in placements]
-
-    # coflow group ids are only consumed by omcoflow (and custom policies);
-    # the built-in oes / oes_strict / fifo / mrtf rules ignore them, so the
-    # per-event group computation (and the numpy `delivered` mirror it
-    # gathers from) is skipped for those.  Shaping keeps the BASE policy's
-    # traits: strict mode is still a pure function of the flow topology
-    # (classes are fixed per column), deadline mode reads remaining + clock
-    # and must be recomputed every event — unless no flow in the whole
-    # batch carries a finite deadline, where it IS strict and keeps the
-    # caches (and skips the per-event escalation-wake scan).
-    dl_events = (
-        shaped and policy.mode == "deadline" and bool(np.isfinite(flow_dl).any())
-    )
-    _, needs_group, rates_cacheable, topo_cacheable = _policy_traits(
-        policy, inert_deadlines=shaped and policy.mode == "deadline" and not dl_events
-    )
-    delivered_np = np.zeros((B, EG), dtype=np.int64) if needs_group else None
-    sending = np.zeros((B, EG), dtype=np.int64)
-    remaining = np.zeros((B, EG), dtype=np.float64)
-    release = np.zeros((B, EG), dtype=np.float64)
-    active = np.zeros((B, EG), dtype=bool)
-
-    in_edges, out_edges = workload.in_edges, workload.out_edges
-    heaps: List[List[Tuple[float, int, int]]] = [[] for _ in range(B)]
-    events: List[List[TaskEvent]] = [[] for _ in range(B)]
-    flow_logs: List[List[Tuple[int, int, float, float]]] = [[] for _ in range(B)]
-    flow_starts: List[Dict[Tuple[int, int], float]] = [{} for _ in range(B)]
-    n_events = np.zeros(B, dtype=np.int64)
-    t = np.zeros(B, dtype=np.float64)
-
-    rates_fn = _batch_rates_factory(
-        policy, B, cluster, (J + Gmax) * (N + 2), bw_in_mat, bw_out_mat,
-        dynamic=trace is not None,
-    )
-    # oes / oes_strict / fifo rates depend only on the active-flow TOPOLOGY
-    # (machine ids + release order), not on ``remaining`` — an instance's
-    # per-flow rates stay valid until a flow starts or completes, so only
-    # "dirty" instances re-enter the (expensive) rate computation.  mrtf /
-    # omcoflow read ``remaining`` and must be recomputed every event.
-    rate_cache = np.zeros((B, EG), dtype=np.float64)
-    dirty = np.ones(B, dtype=bool)
-    # oes / oes_strict rates are a pure function of the active EDGE SET
-    # (placement fixed per instance, bw shared) — and training iterations
-    # revisit the same flow frontiers over and over, so memoise per-instance
-    # rates by active-set key (classes are part of the key for free: a
-    # column's class never changes).  fifo additionally depends on release
-    # times, so it only gets the dirty-tracking cache above.
-    topo_caches: List[Dict[bytes, np.ndarray]] = [{} for _ in range(B)]
-
-    # Hot per-(b, e) lookups in the completion handlers go through plain
-    # Python lists — several times cheaper than numpy scalar indexing.
-    lag_l = lag.tolist()
-    src_t_l = src_t.tolist()
-    dst_t_l = dst_t.tolist()
-    last_l = last_instance.tolist()
-    local_l = [row.tolist() for row in local]
-    vol_l = [row.tolist() for row in vol]  # [B][E][N]
-    ex_l = [row.tolist() for row in ex]  # [B][J][N]
-    done_l = [[0] * J for _ in range(B)]
-    running_l = [[False] * J for _ in range(B)]
-    delivered = [[0] * EG for _ in range(B)]
-    n_active = [0] * B  # active-flow count per instance
-    mig_left_l = [[0] * J for _ in range(B)]  # pending gating state flows
-    mig_task_l = [[f.task for f in ms] for ms in mig_lists]
-
-    def can_start(b: int, j: int, n: int) -> bool:
-        if n > N or running_l[b][j] or done_l[b][j] != n - 1:
-            return False
-        if n == 1 and mig_left_l[b][j]:
-            return False  # relocated: first iteration waits for its state
-        loc = local_l[b]
-        done = done_l[b]
-        dlv = delivered[b]
-        for e in in_edges[j]:
-            need = n - lag_l[e]
-            if need <= 0:
-                continue
-            if loc[e]:
-                if done[src_t_l[e]] < need:
-                    return False
-            elif dlv[e] < need:
-                return False
-        return True
-
-    def start_task(b: int, j: int, n: int, tb: float) -> None:
-        running_l[b][j] = True
-        if slow_l is None:
-            end = tb + ex_l[b][j][n - 1]
-        else:
-            end = tb + ex_l[b][j][n - 1] * slow_l[b][y_l[b][j]]
-        heapq.heappush(heaps[b], (end, j, n))
-        if record:
-            events[b].append(TaskEvent(j, n, tb, end))
-
-    def try_start_flow(b: int, e: int, tb: float) -> bool:
-        if local_l[b][e] or active[b, e]:
-            return False
-        got_zero = False
-        dlv = delivered[b]
-        ve = vol_l[b][e]
-        while True:
-            nxt = dlv[e] + 1
-            if nxt > last_l[e] or done_l[b][src_t_l[e]] < nxt:
-                return got_zero
-            if ve[nxt - 1] > EPS:
-                break
-            dlv[e] = nxt
-            if needs_group:
-                delivered_np[b, e] = nxt
-            got_zero = True
-        sending[b, e] = nxt
-        remaining[b, e] = ve[nxt - 1]
-        release[b, e] = tb
-        active[b, e] = True
-        n_active[b] += 1
-        dirty[b] = True
-        if record:
-            flow_starts[b][(e, nxt)] = tb
-        return got_zero
-
-    for b, ms in enumerate(mig_lists):
-        for g, f in enumerate(ms):
-            e = E + g
-            if local[b, e]:
-                delivered[b][e] = 1
-                if needs_group:
-                    delivered_np[b, e] = 1
-                continue
-            sending[b, e] = 1
-            remaining[b, e] = vol[b, e, 0]
-            active[b, e] = True
-            n_active[b] += 1
-            if f.task >= 0:
-                mig_left_l[b][f.task] += 1
-            if record:
-                flow_starts[b][(e, 1)] = 0.0
-
-    for b in range(B):
-        for j in range(J):
-            if can_start(b, j, 1):
-                start_task(b, j, 1, 0.0)
-
-    alive = np.array([bool(heaps[b]) or n_active[b] > 0 for b in range(B)])
-    iters = 0
-    flow_cls_flat = flow_cls.ravel()
-    flow_dl_flat = flow_dl.ravel()
-    with np.errstate(divide="ignore", invalid="ignore"):
-        while alive.any():
-            n_events[alive] += 1
-            iters += 1
-            if iters > max_events:  # pragma: no cover
-                raise RuntimeError("event limit exceeded — dependency deadlock?")
-            # finished instances have no active flows and an empty heap, so
-            # ``active`` alone identifies every live flow
-            rows, cols = np.nonzero(active)  # row-major: sorted by instance
-            t_flow = np.full(B, np.inf)
-            if rows.size:
-                flat = rows * EG + cols
-                rem_f = remaining.ravel()[flat]
-                if rates_cacheable:
-                    if dirty.any():
-                        dmask = dirty[rows]
-                        drows = rows[dmask]
-                        if drows.size and not topo_cacheable:
-                            dflat = flat[dmask]
-                            rate_cache.ravel()[dflat] = rates_fn(
-                                drows, src_m.ravel()[dflat],
-                                dst_m.ravel()[dflat], rem_f[dmask],
-                                release.ravel()[dflat], None,
-                                flow_cls_flat[dflat] if shaped else None,
-                                flow_dl_flat[dflat] if shaped else None,
-                                t[drows] if shaped else None,
-                            )
-                        elif drows.size:
-                            dflat = flat[dmask]
-                            dcols = cols[dmask]
-                            cut = np.empty(len(drows), dtype=bool)
-                            cut[0] = True
-                            np.not_equal(drows[1:], drows[:-1], out=cut[1:])
-                            bounds = np.nonzero(cut)[0].tolist()
-                            bounds.append(len(drows))
-                            miss: List[Tuple[int, int, int, bytes]] = []
-                            rc_flat = rate_cache.ravel()
-                            for lo, hi in zip(bounds[:-1], bounds[1:]):
-                                b = int(drows[lo])
-                                key = dcols[lo:hi].tobytes()
-                                got = topo_caches[b].get(key)
-                                if got is not None:
-                                    rc_flat[dflat[lo:hi]] = got
-                                else:
-                                    miss.append((b, lo, hi, key))
-                            if miss:
-                                sel = np.concatenate(
-                                    [np.arange(lo, hi) for _, lo, hi, _ in miss]
-                                )
-                                mflat = dflat[sel]
-                                rr = rates_fn(
-                                    drows[sel], src_m.ravel()[mflat],
-                                    dst_m.ravel()[mflat],
-                                    remaining.ravel()[mflat],
-                                    release.ravel()[mflat], None,
-                                    flow_cls_flat[mflat] if shaped else None,
-                                    flow_dl_flat[mflat] if shaped else None,
-                                    t[drows[sel]] if shaped else None,
-                                )
-                                rc_flat[mflat] = rr
-                                k = 0
-                                for b, lo, hi, key in miss:
-                                    topo_caches[b][key] = rr[k : k + hi - lo].copy()
-                                    k += hi - lo
-                        dirty[:] = False
-                    rates = rate_cache.ravel()[flat]
-                else:
-                    grp = None
-                    if needs_group:
-                        grp = (
-                            dst_t_grp[cols] * (N + 2)
-                            + delivered_np.ravel()[flat] + 1 + lag_grp[cols]
-                        )
-                    rates = rates_fn(
-                        rows, src_m.ravel()[flat], dst_m.ravel()[flat], rem_f,
-                        release.ravel()[flat], grp,
-                        flow_cls_flat[flat] if shaped else None,
-                        flow_dl_flat[flat] if shaped else None,
-                        t[rows] if shaped else None,
-                    )
-                dt = np.where(rates > EPS, rem_f / np.maximum(rates, EPS), np.inf)
-                counts = np.bincount(rows, minlength=B)
-                seg = counts > 0
-                starts = np.zeros(B, dtype=np.int64)
-                np.cumsum(counts[:-1], out=starts[1:])
-                t_flow[seg] = np.minimum.reduceat(dt, starts[seg])
-            t_flow = t + t_flow
-            t_task = np.array(
-                [heaps[b][0][0] if heaps[b] else np.inf for b in range(B)]
-            )
-            t_next = np.minimum(np.minimum(t_task, t_flow), t_break)
-            # deadline shaping: per-instance earliest possible escalation,
-            # mirroring the scalar engine's fourth event source bit-for-bit
-            if dl_events and rows.size:
-                cand = (
-                    np.isfinite(flow_dl_flat[flat])
-                    & (flow_cls_flat[flat] > CLASS_TRAINING)
-                )
-                if cand.any():
-                    rsel = rows[cand]
-                    csel = flat[cand]
-                    lim = np.minimum(
-                        bw_in_mat[rsel, dst_m.ravel()[csel]],
-                        bw_out_mat[rsel, src_m.ravel()[csel]],
-                    )
-                    esc = flow_dl_flat[csel] - remaining.ravel()[csel] / np.maximum(lim, EPS)
-                    fut = esc > t[rsel] + EPS
-                    if fut.any():
-                        t_esc = np.full(B, np.inf)
-                        np.minimum.at(t_esc, rsel[fut], esc[fut])
-                        t_next = np.minimum(t_next, t_esc)
-            if bool((alive & ~np.isfinite(t_next)).any()):  # pragma: no cover
-                raise RuntimeError("no progress: flows active but zero rates")
-
-            fins: Dict[int, List[int]] = {}
-            if rows.size:
-                rem_f = rem_f - rates * (t_next[rows] - t[rows])
-                remaining.ravel()[flat] = rem_f
-                vol_f = vol.ravel()[flat * N + sending.ravel()[flat] - 1]
-                fin_mask = rem_f <= EPS * np.maximum(1.0, vol_f)
-                for b, e in zip(rows[fin_mask].tolist(), cols[fin_mask].tolist()):
-                    fins.setdefault(b, []).append(e)
-            np.copyto(t, t_next, where=alive)
-
-            if trace is not None:
-                # mirror the scalar engine's ordering: segments advance
-                # before this event's completion handlers, so tasks started
-                # AT a boundary already see the new slowdown (and the next
-                # rate computation the new bandwidth).
-                for b in np.nonzero(alive & (t >= t_break))[0].tolist():
-                    s = seg_b[b]
-                    while s + 1 < n_segs and seg_times[s + 1] <= t[b]:
-                        s += 1
-                    seg_b[b] = s
-                    bw_in_mat[b] = trace.bw_in[s]
-                    bw_out_mat[b] = trace.bw_out[s]
-                    slow_l[b] = np.asarray(trace.slow[s], dtype=np.float64).tolist()
-                    t_break[b] = seg_times[s + 1] if s + 1 < n_segs else np.inf
-                    dirty[b] = True
-                    topo_caches[b].clear()  # rates now depend on the new bw
-
-            for b in np.nonzero(alive)[0].tolist():
-                tb = float(t_next[b])
-                heap = heaps[b]
-                touched: List[int] = []
-
-                while heap and heap[0][0] <= tb + EPS:
-                    _, j, n = heapq.heappop(heap)
-                    running_l[b][j] = False
-                    done_l[b][j] = n
-                    touched.append(j)
-                    for e in out_edges[j]:
-                        if local_l[b][e]:
-                            touched.append(dst_t_l[e])
-                        elif try_start_flow(b, e, tb):
-                            touched.append(dst_t_l[e])
-
-                for e in fins.get(b, ()):
-                    n = int(sending[b, e])
-                    delivered[b][e] = n
-                    if needs_group:
-                        delivered_np[b, e] = n
-                    sending[b, e] = 0
-                    active[b, e] = False
-                    remaining[b, e] = 0.0
-                    n_active[b] -= 1
-                    dirty[b] = True
-                    if e >= E:  # one-shot migration flow: state has landed
-                        if record:
-                            flow_logs[b].append(
-                                (int(e), n, flow_starts[b].pop((int(e), n)), tb)
-                            )
-                        tsk = mig_task_l[b][e - E]
-                        if tsk >= 0:
-                            mig_left_l[b][tsk] -= 1
-                            touched.append(tsk)
-                        continue
-                    touched.append(dst_t_l[e])
-                    if record:
-                        flow_logs[b].append(
-                            (int(e), n, flow_starts[b].pop((int(e), n)), tb)
-                        )
-                    if try_start_flow(b, e, tb):
-                        touched.append(dst_t_l[e])
-
-                for j in set(touched):
-                    n = done_l[b][j] + 1
-                    if can_start(b, j, n):
-                        start_task(b, j, n, tb)
-                alive[b] = bool(heap) or n_active[b] > 0
-
+    if migrations is None:
+        migrations = [None] * B
     return [
-        ScheduleResult(
-            makespan=float(t[b]),
-            task_events=events[b],
-            flow_log=flow_logs[b] if record else None,
-            n_events=int(n_events[b]),
-            policy=policy.name,
+        _simulate_reference(
+            workload, cluster, placements[b], realizations[b], policy, record,
+            max_events, trace, migrations[b], shaping, edge_classes,
         )
         for b in range(B)
     ]
@@ -1707,38 +1036,19 @@ def expected_makespan(
     n_iters: int = 20,
     n_draws: int = 3,
     seed: int = 0,
-    batch: Optional[bool] = None,
     backend: Optional[str] = None,
 ) -> Seconds:
     """Monte-Carlo estimate of T'_Y (paper §V-B): simulate ``n_iters``
     iterations a few times with fresh draws from the traffic profile.
 
-    With ``batch`` (default: whenever ``n_draws > 1``) all draws advance in
-    one fused ``simulate_batch`` call — bit-identical result, one event loop.
-    ``backend`` is threaded to the engine (see ``resolve_backend``)."""
-    if batch is None:
-        batch = n_draws > 1
+    All draws run in one ``simulate_batch`` call; ``backend`` is threaded
+    to the engine (see ``resolve_backend``)."""
     reals = monte_carlo_draws(
         workload, seed=seed, n_iters=n_iters, n_draws=n_draws
     )
-    if batch:
-        results = simulate_batch(
-            workload, cluster, [placement] * n_draws, reals, policy=policy,
-            backend=backend,
-        )
-        makespans = [r.makespan for r in results]
-    else:
-        makespans = [
-            simulate(
-                workload, cluster, placement, r, policy=policy,
-                backend=backend,
-            ).makespan
-            for r in reals
-        ]
-    total = 0.0
-    for m in makespans:
-        total += m
-    return total / n_draws
+    return mean_batch_makespans(
+        workload, cluster, [(placement, reals)], policy=policy, backend=backend
+    )[0]
 
 
 def mean_batch_makespans(

@@ -287,8 +287,8 @@ def merged_batch_cost(
     jobs need the epsilon padding of ``realize_merged`` — and the merged
     workload refuses, see ``Workload.is_merged``), so the batch is sized
     here: every candidate placement is simulated against the same
-    ``n_draws`` merged realizations in ONE ``simulate_batch`` call — batch
-    width = len(placements) x n_draws.  Draw ``d`` realizes under
+    ``n_draws`` merged realizations in ONE ``simulate_batch`` call of
+    len(placements) x n_draws instances.  Draw ``d`` realizes under
     ``derive_seed(seed, SEED_NS_DRAW, d)``, a namespace disjoint from the
     per-job streams inside each draw.  Plug into
     ``etp_multichain(batch_cost_fn=...)``."""

@@ -536,8 +536,8 @@ def etp_search(
     ``cost_fn`` may override the simulated-makespan cost (used by tests and
     by the infeed planner); the default is the paper's eq. (21):
     ``T'_Y * (1 + violation%)`` with T'_Y from OES simulation driven by the
-    workload's traffic profile.  With ``sim_draws > 1`` the draws run in one
-    fused ``simulate_batch`` call.
+    workload's traffic profile; the ``sim_draws`` draws of an evaluation run
+    in one ``simulate_batch`` call.
 
     Beyond-paper extensions, both ablatable back to Alg. 3 semantics
     (``group_moves=0, anneal=False, beta=0.1``) and benchmarked in
@@ -595,7 +595,7 @@ def _best_of(a: Optional[ETPResult], b: ETPResult) -> ETPResult:
 
 def _chain_defaults() -> Dict[str, object]:
     """The _Chain keyword defaults, read off ``etp_search``'s signature so
-    the batched and sequential multichain paths can never drift apart."""
+    a multichain chain and an ``etp_search`` chain can never drift apart."""
     sig = inspect.signature(etp_search)
     return {
         k: sig.parameters[k].default
@@ -614,31 +614,29 @@ def etp_multichain(
     budget: int = 2000,
     seed: int = 0,
     include_baseline_inits: bool = True,
-    use_batch: bool = True,
     batch_cost_fn: Optional[Callable[[Sequence[Placement]], List[float]]] = None,
     time_budget_s: Optional[float] = None,
     **kw: Any,
 ) -> ETPResult:
     """Beyond-paper: independent MCMC chains from diverse starts (random IFS
-    machine orders + the DistDGL colocation heuristic), best-of.  Chains are
-    embarrassingly parallel, and with ``use_batch`` (default) they advance in
+    machine orders + the DistDGL colocation heuristic), best-of.  Chain
+    ``c`` runs ``budget // n_chains`` transitions from seed
+    ``derive_seed(seed, SEED_NS_CHAIN, c)``; chain 1 starts from the DistDGL
+    placement when ``include_baseline_inits``.  The chains advance in
     LOCK-STEP: each step, every chain's proposal is evaluated in ONE
-    ``simulate_batch`` call (batch width = pending chains x sim_draws), so
-    placement-evaluations/sec scale with the chain count while per-chain
-    semantics — rng streams, caches, accept rules — stay bit-identical to the
-    sequential path (benchmarks/bench_etp.py measures the speedup).
+    ``simulate_batch`` call (batch width = pending chains x sim_draws),
+    while each chain keeps its own rng stream, cost cache and accept rule
+    exactly as one ``etp_search`` chain with that seed, budget and init
+    would.
 
     ``batch_cost_fn`` (many placements -> makespans) overrides the simulated
     cost for externally-batched objectives, e.g. multi-job merged workloads
-    (core/multijob.py).  With ``use_batch=False`` chains run sequentially
-    with a shared per-chain budget so total simulation work matches a
-    single-chain run of ``budget`` transitions; ``time_budget_s`` then
-    applies per chain rather than globally.
+    (core/multijob.py); an explicit scalar ``cost_fn`` (via ``**kw``) beats
+    it.  ``time_budget_s`` bounds the whole search's wall time.
 
     ``backend=`` (via ``**kw``, see ``etp_search``) moves the pooled
     lock-step evaluations onto the selected simulation engine — with
-    ``"jax"`` every step's proposals are ONE jitted batch, which is where
-    the backend pays most (benchmarks/bench_engine.py)."""
+    ``"jax"`` every step's proposals are ONE jitted batch."""
     per = max(1, budget // n_chains)
 
     def chain_init(c: int) -> Optional[Placement]:
@@ -648,35 +646,6 @@ def etp_multichain(
             except ValueError:
                 return None
         return None
-
-    if not use_batch:
-        seq_kw = dict(kw)
-        if batch_cost_fn is not None and seq_kw.get("cost_fn") is None:
-            seq_kw["cost_fn"] = lambda p: batch_cost_fn([p])[0]
-        best: Optional[ETPResult] = None
-        stats: List[dict] = []
-        for c in range(n_chains):
-            chain_seed = derive_seed(seed, SEED_NS_CHAIN, c)
-            r = etp_search(
-                workload, cluster, budget=per, seed=chain_seed,
-                init=chain_init(c), time_budget_s=time_budget_s, **seq_kw,
-            )
-            stats.append(
-                {
-                    "seed": chain_seed,
-                    "evaluations": r.evaluations,
-                    "cache_hits": r.cache_hits,
-                    "proposals": r.proposals,
-                    "accepted": r.accepted,
-                    "acceptance_rate": r.accepted / max(r.proposals, 1),
-                    "best_makespan": float(r.best_makespan),
-                    "objective_trajectory": [float(c_) for c_ in r.cost_trace],
-                }
-            )
-            best = _best_of(best, r)
-        assert best is not None
-        best.chain_stats = stats
-        return best
 
     t0 = time.perf_counter()
     params = _chain_defaults()
@@ -708,8 +677,7 @@ def etp_multichain(
             else:
                 need.append(i)
         if need:
-            # same objective precedence as the sequential path: an explicit
-            # scalar cost_fn beats batch_cost_fn beats simulation
+            # an explicit scalar cost_fn beats batch_cost_fn beats simulation
             if explicit_cost_fn is not None:
                 ts = [explicit_cost_fn(pairs[i][1]) for i in need]
             elif batch_cost_fn is not None:

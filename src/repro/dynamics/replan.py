@@ -439,9 +439,9 @@ class Replanner:
         ) -> Tuple[float, float, List[MigrationFlow]]:
             """(clean, loaded, flows) mean makespans; the loaded variant
             injects ``migs`` under ``cfg.shaping`` — with strict/no shaping
-            both variants run in ONE lock-step batch (a shaped policy with
-            no migration flows is a bit-identical pass-through, so the
-            clean legs stay comparable to unshaped records).  Deadline
+            both variants run in ONE ``simulate_batch`` call (a shaped
+            policy with no migration flows is a bit-identical pass-through,
+            so the clean legs stay comparable to unshaped records).  Deadline
             shaping needs the clean variant FIRST: it is recorded, the
             gated flows' deadlines are filled from its task starts
             (``annotate_deadlines``), and the loaded variant runs second —

@@ -1,11 +1,14 @@
-"""Batched lock-step engine: exactness certificates.
+"""Batched evaluation on the numpy reference: exactness certificates.
 
-``simulate_batch`` promises BIT-IDENTICAL results to ``simulate`` run on
-each (placement, realization) instance alone, for every rate policy — this
-is what lets ETP's batched planning loop claim the scalar engine's
-semantics at a fraction of the wall time.  The slotted transcription of
-Alg. 1 (oes_slotted.py) stays the fidelity anchor: the batched engine must
-agree with it in the slot->0 limit exactly like the scalar engine does.
+``simulate_batch`` on numpy is the reference ``simulate`` run instance by
+instance, so its results are BIT-IDENTICAL to ``simulate`` on each
+(placement, realization) alone, for every rate policy.  The batched
+callers built on it (``expected_makespan``, ``expected_makespan_many``,
+lock-step ``etp_multichain``, the merged-job objective) are pinned here to
+the loops of scalar calls they stand for.  The slotted transcription of
+Alg. 1 (oes_slotted.py) stays the fidelity anchor in the slot->0 limit.
+(The jitted lock-step engine is pinned to this reference at
+``PARITY_RTOL`` in tests/test_jax_engine.py.)
 """
 import numpy as np
 import pytest
@@ -16,18 +19,20 @@ from repro.core import (
     expected_makespan_many,
     heterogeneous_cluster,
     ifs_placement,
+    monte_carlo_draws,
     simulate,
     simulate_batch,
     simulate_slotted,
 )
 from repro.core.multijob import (
+    SEED_NS_CHAIN,
     SEED_NS_DRAW,
     derive_seed,
     merge_workloads,
     merged_batch_cost,
     realize_merged,
 )
-from repro.core.placement import etp_multichain
+from repro.core.placement import distdgl_placement, etp_multichain, etp_search
 
 ALL_POLICIES = ("oes", "oes_strict", "fifo", "mrtf", "omcoflow")
 
@@ -73,13 +78,17 @@ def test_batch_matches_scalar_exactly(policy):
 
 
 def test_fused_expected_makespan_matches_loop():
+    """expected_makespan == the mean of one scalar simulate per canonical
+    draw (``monte_carlo_draws``), summed in order."""
     wl = small_job(seed=1)
     cluster = heterogeneous_cluster(3, seed=1)
     p = ifs_placement(wl, cluster, seed=0)
     for n_draws in (1, 2, 4):
-        loop = expected_makespan(wl, cluster, p, n_draws=n_draws, batch=False)
-        fused = expected_makespan(wl, cluster, p, n_draws=n_draws, batch=True)
-        assert loop == fused, n_draws
+        total = 0.0
+        for r in monte_carlo_draws(wl, seed=5, n_iters=20, n_draws=n_draws):
+            total += simulate(wl, cluster, p, r).makespan
+        fused = expected_makespan(wl, cluster, p, n_draws=n_draws, seed=5)
+        assert fused == total / n_draws, n_draws
 
 
 def test_expected_makespan_many_matches_per_placement():
@@ -88,7 +97,7 @@ def test_expected_makespan_many_matches_per_placement():
     placements = [ifs_placement(wl, cluster, seed=s) for s in range(4)]
     many = expected_makespan_many(wl, cluster, placements, n_draws=2, seed=3)
     ref = [
-        expected_makespan(wl, cluster, p, n_draws=2, seed=3, batch=False)
+        expected_makespan(wl, cluster, p, n_draws=2, seed=3)
         for p in placements
     ]
     assert many == ref
@@ -107,14 +116,46 @@ def test_batched_engine_matches_slotted_oracle():
         assert sl == pytest.approx(ev, rel=tol), (slot, sl, ev)
 
 
+def _sequential_chains(wl, cluster, *, n_chains, budget, seed, **kw):
+    """What ``etp_multichain`` promises, written as a loop: one
+    ``etp_search`` per chain with ``budget // n_chains`` transitions, seed
+    ``derive_seed(seed, SEED_NS_CHAIN, c)``, chain 1 from the DistDGL
+    placement; best of (feasible first, then lowest makespan).  Returns
+    the best result and every chain's result."""
+    runs = [
+        etp_search(
+            wl, cluster, budget=max(1, budget // n_chains),
+            seed=derive_seed(seed, SEED_NS_CHAIN, c),
+            init=distdgl_placement(wl, cluster) if c == 1 else None, **kw,
+        )
+        for c in range(n_chains)
+    ]
+    best = runs[0]
+    for r in runs[1:]:
+        if (best.fallback, best.best_makespan) > (r.fallback, r.best_makespan):
+            best = r
+    return best, runs
+
+
+def _assert_same_chains(bat, runs):
+    """Each lock-step chain's telemetry row equals its etp_search run."""
+    assert len(bat.chain_stats) == len(runs)
+    for st, r in zip(bat.chain_stats, runs):
+        assert st["objective_trajectory"] == [float(c) for c in r.cost_trace]
+        assert (st["evaluations"], st["cache_hits"]) == (r.evaluations, r.cache_hits)
+        assert (st["proposals"], st["accepted"]) == (r.proposals, r.accepted)
+
+
 def test_multichain_batch_matches_sequential():
-    """Lock-step batched chains == sequential chains: same best placement,
-    same makespan, same cost trace, same eval/cache counters."""
+    """Lock-step batched chains == a loop of single etp_search chains: same
+    per-chain trajectories and counters, same best placement, makespan,
+    cost trace and eval/cache counters."""
     wl = small_job(seed=5, n_iters=8)
     cluster = heterogeneous_cluster(4, seed=6)
     kw = dict(n_chains=3, budget=45, sim_iters=8, sim_draws=2, seed=0)
-    seq = etp_multichain(wl, cluster, use_batch=False, **kw)
-    bat = etp_multichain(wl, cluster, use_batch=True, **kw)
+    seq, runs = _sequential_chains(wl, cluster, **kw)
+    bat = etp_multichain(wl, cluster, **kw)
+    _assert_same_chains(bat, runs)
     assert np.array_equal(seq.placement.y, bat.placement.y)
     assert seq.best_makespan == bat.best_makespan
     assert seq.cost_trace == bat.cost_trace
@@ -123,21 +164,30 @@ def test_multichain_batch_matches_sequential():
 
 
 def test_multichain_explicit_cost_fn_beats_batch_cost_fn():
-    """An explicit scalar cost_fn wins over batch_cost_fn on BOTH paths
-    (the batched path must not silently optimise a different objective)."""
+    """An explicit scalar cost_fn wins over batch_cost_fn: the lock-step
+    search optimises the same objective as a loop of etp_search chains
+    under that cost_fn, and never consults batch_cost_fn."""
     wl = small_job(seed=6, n_iters=6)
     cluster = heterogeneous_cluster(3, seed=3)
 
     def scalar_cost(p):
         return float(np.sum(p.y))  # deterministic, trivially cheap
 
+    consulted = []
+
     def batch_cost(ps):
+        consulted.append(len(ps))
         return [1e9] * len(ps)  # would wreck the search if ever consulted
 
-    kw = dict(n_chains=2, budget=20, seed=0, cost_fn=scalar_cost,
-              batch_cost_fn=batch_cost)
-    seq = etp_multichain(wl, cluster, use_batch=False, **kw)
-    bat = etp_multichain(wl, cluster, use_batch=True, **kw)
+    seq, runs = _sequential_chains(
+        wl, cluster, n_chains=2, budget=20, seed=0, cost_fn=scalar_cost
+    )
+    bat = etp_multichain(
+        wl, cluster, n_chains=2, budget=20, seed=0, cost_fn=scalar_cost,
+        batch_cost_fn=batch_cost,
+    )
+    assert not consulted
+    _assert_same_chains(bat, runs)
     assert seq.best_makespan == bat.best_makespan
     assert seq.cost_trace == bat.cost_trace
     assert bat.best_makespan < 1e9
